@@ -1,7 +1,9 @@
-"""Wall-clock of the production batched-intersection paths (jnp reference
-vs Pallas-in-interpret sanity) and the SeCluD search service device path
-vs baseline single-index execution. On CPU these numbers are engineering
-sanity checks; the TPU numbers come from the roofline analysis."""
+"""Wall-clock of the batched-intersection paths and the SeCluD search
+service's packed device path (``device/seclud_packed``) against baseline
+single-index execution.  On a TPU that row runs the compiled Pallas
+intersect kernels; elsewhere it runs the jnp reference, and the numbers
+are CPU sanity checks, not device numbers.  No roofline of the kernels
+exists yet."""
 
 import numpy as np
 

@@ -11,12 +11,18 @@ Prints ``name,us_per_call,derived`` CSV rows.
 ``--smoke`` exists so every CI run appends one comparable data point to the
 perf trajectory: quick sizes, a fixed suite subset, and a JSON artifact
 (``--out``) the workflow uploads.
+
+A suite that raises prints an ``ERROR`` row and makes the run exit
+non-zero in every mode.  Compiles go through the persistent cache of
+:mod:`repro.compile_cache` (``<repo>/.jax_cache`` unless
+``JAX_COMPILATION_CACHE_DIR`` is set).
 """
 
 import argparse
 import json
 import sys
 import time
+from pathlib import Path
 
 # Fast, deterministic-size suites: one clustering row, one index row, one
 # kernel row, one serving-replay row set.  The heavy sweeps (scaling,
@@ -35,6 +41,10 @@ def main() -> None:
                     help="artifact path for --smoke")
     args = ap.parse_args()
     quick = not args.full
+
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache(Path(__file__).resolve().parents[1])
 
     from benchmarks import (
         bench_chaos,
@@ -76,7 +86,7 @@ def main() -> None:
             for r in mod.run(quick=quick):
                 print(r, flush=True)
                 rows.append(r)
-        except Exception as e:  # pragma: no cover
+        except Exception as e:
             print(f"{name}/ERROR,0,{type(e).__name__}:{e}", flush=True)
             errors.append({"suite": name, "error": f"{type(e).__name__}: {e}"})
     total_s = time.time() - t0
@@ -110,10 +120,10 @@ def main() -> None:
                 indent=2,
             )
         print(f"# wrote {args.out} ({len(parsed)} rows)", file=sys.stderr)
-        if errors:
-            # A silent hole in the perf trajectory is worse than a red CI
-            # job: fail loudly when a smoke suite breaks.
-            sys.exit(1)
+    if errors:
+        # A silent hole in the perf trajectory is worse than a red run:
+        # fail loudly when any suite breaks, in every mode.
+        sys.exit(1)
 
 
 if __name__ == "__main__":

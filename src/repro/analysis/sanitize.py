@@ -146,6 +146,4 @@ def jit_cache_size(fn) -> int:
     probe = getattr(fn, "_cache_size", None)
     if callable(probe):
         return int(probe())
-    raise AttributeError(
-        f"{fn!r} exposes no jit cache size probe on this jax version"
-    )
+    raise AttributeError(f"{fn!r} is not a jitted callable (no cache probe)")

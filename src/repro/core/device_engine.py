@@ -484,13 +484,10 @@ def device_fold(
 
 def fold_cache_size() -> int:
     """Compiled-entry count of the fused fold — the serving loop's
-    compile counter.  0 when this jax version exposes no cache probe."""
+    compile counter."""
     from repro.analysis.sanitize import jit_cache_size
 
-    try:
-        return jit_cache_size(_fused_fold)
-    except AttributeError:  # pragma: no cover - other jax versions
-        return 0
+    return jit_cache_size(_fused_fold)
 
 
 def plan_shape_key(lowered: LoweredPlan) -> Tuple[int, int, Tuple[int, ...], int]:
@@ -1054,9 +1051,7 @@ def _build_sharded_fold(
     single ``psum`` over the data axes produces the global counts —
     cached so batches of similar size reuse one executable, exactly like
     the single-device jit cache."""
-    import inspect
-
-    from jax.experimental.shard_map import shard_map
+    from jax.sharding import PartitionSpec as P
 
     from repro.dist import sharding as sh
 
@@ -1080,26 +1075,17 @@ def _build_sharded_fold(
             return counts, entering, cur[None]
         return counts, entering
 
-    # check_rep=False where supported: the body nests the fused fold,
-    # whose replication jax 0.4.x's checker cannot track; the psum is
-    # what establishes the replication of the counts.
-    kw = {}
-    try:
-        if "check_rep" in inspect.signature(shard_map).parameters:
-            kw["check_rep"] = False
-    except (ValueError, TypeError):  # pragma: no cover
-        pass
-    from jax.sharding import PartitionSpec as P
-
     out_specs = (P(), P())
     if return_members:
         out_specs = out_specs + (sh.postings_spec(mesh),)
-    fn = shard_map(
+    # check_vma=False: the psum over the data axes is what makes the
+    # counts replicated; the fold's gathers carry no varying-axis types.
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(sh.postings_spec(mesh), cells_spec, seg_spec),
         out_specs=out_specs,
-        **kw,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -1182,10 +1168,7 @@ def sharded_device_counts(
         bool(return_docs),
     )
     t_lower = time.perf_counter() - t1
-    try:
-        cache_before = jit_cache_size(fold)
-    except AttributeError:  # pragma: no cover - other jax versions
-        cache_before = None
+    cache_before = jit_cache_size(fold)
     # Explicit per-batch upload, pre-placed shard-per-row so the jit
     # never reshards (and never transfers implicitly).
     from jax.sharding import NamedSharding
@@ -1203,11 +1186,7 @@ def sharded_device_counts(
     )
     counts = jax.device_get(out[0])[: lowered.n_queries].astype(np.int64)
     t_fold = time.perf_counter() - t2
-    compiles = (
-        0.0
-        if cache_before is None
-        else float(jit_cache_size(fold) - cache_before)
-    )
+    compiles = float(jit_cache_size(fold) - cache_before)
     total_true = float(lowered.n_cells_true.sum())
     max_true = float(lowered.n_cells_true.max())
     # Per-shard dispatch times for the straggler monitor.  The fused

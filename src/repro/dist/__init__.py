@@ -6,8 +6,9 @@ useful ... for distributing the work over many machines" — realized as four
 modules:
 
 * ``sharding``        — PartitionSpec rules for every param/batch/cache tree
-                        the launch layer builds, plus a version-portable
-                        ambient-mesh context (``set_mesh``/``get_active_mesh``).
+                        the launch layer builds, plus the ambient mesh the
+                        model's ``shard_map`` paths read
+                        (``set_mesh``/``get_active_mesh``).
 * ``cluster_dist``    — mesh-sharded SeCluD K-means (``shard_map`` + ``psum``)
                         and adapters that drop it into ``multilevel_cluster``
                         / ``topdown_cluster``.

@@ -25,7 +25,6 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.jax_ops import (
@@ -61,23 +60,15 @@ def make_round_fn(mesh, k: int, tc: int, block: int = 512) -> Callable:
         scores = scores_from_ell(ell_loc, tables, p, block=block)
         return jnp.argmin(scores, axis=1).astype(assign_loc.dtype), psi
 
-    # check_rep=False: the body nests jit'd ops (counts/psi/tables) whose
-    # replication jax 0.4.x's checker cannot track through; the psum over
-    # the data axes is what actually establishes the replication of ψ.
-    kw = {}
-    try:
-        import inspect
-
-        if "check_rep" in inspect.signature(shard_map).parameters:
-            kw["check_rep"] = False
-    except (ValueError, TypeError):  # pragma: no cover
-        pass
-    fn = shard_map(
+    # check_vma=False: the psum over the data axes is what establishes
+    # the replication of ψ; the nested jit'd ops carry no varying-axis
+    # types for the checker to follow.
+    fn = jax.shard_map(
         local_round,
         mesh=mesh,
         in_specs=(P(dp, None), P(dp), P()),
         out_specs=(P(dp), P()),
-        **kw,
+        check_vma=False,
     )
     return jax.jit(fn)
 

@@ -19,10 +19,8 @@ axis that does not evenly divide the corresponding dim to replicated — the
 same tree of rules therefore works for the 1×1 CPU smoke mesh, the 16×16
 production pod, and the 2×16×16 multi-pod mesh.
 
-This module is also the version-portability seam for the ambient mesh:
-``jax.sharding.set_mesh`` / ``get_abstract_mesh`` only exist on newer jax,
-so :func:`set_mesh` / :func:`get_active_mesh` back-fill them with a module
-global holding the concrete mesh (``shard_map`` accepts either).
+It also holds the ambient mesh that the model's ``shard_map`` paths
+read: :func:`set_mesh` / :func:`get_active_mesh`.
 """
 
 from __future__ import annotations
@@ -54,39 +52,27 @@ __all__ = [
 
 
 # ---------------------------------------------------------------------------
-# Ambient mesh context (version-portable)
+# Ambient mesh
 # ---------------------------------------------------------------------------
 
 _ACTIVE_MESH: Optional[Mesh] = None
 
 
 def set_mesh(mesh: Optional[Mesh]) -> Optional[Mesh]:
-    """Make ``mesh`` the ambient mesh the shard_map model paths see.
+    """Make ``mesh`` the ambient mesh the model's ``shard_map`` paths
+    read through :func:`get_active_mesh` (``None`` clears it).
 
-    On jax versions that ship ``jax.sharding.set_mesh`` this delegates to it
-    (so ``get_abstract_mesh`` works natively inside traces); on older
-    versions the mesh is kept in a module global that
-    :func:`get_active_mesh` returns.  Pass ``None`` to clear.
-    """
+    The mesh is only recorded here, never passed to ``jax.set_mesh``:
+    that would switch tracing into explicit-sharding mode, which the
+    model code (auto-sharded jit plus explicit ``shard_map``) is not
+    written for."""
     global _ACTIVE_MESH
     _ACTIVE_MESH = mesh
-    native = getattr(jax.sharding, "set_mesh", None)
-    if native is not None:
-        native(mesh)
     return mesh
 
 
 def get_active_mesh() -> Optional[Mesh]:
-    """The ambient mesh, or None when no mesh has been set.
-
-    Prefers jax's native abstract-mesh context when it exists and is
-    non-trivial, falling back to the mesh stored by :func:`set_mesh`.
-    """
-    native = getattr(jax.sharding, "get_abstract_mesh", None)
-    if native is not None:
-        mesh = native()
-        if mesh is not None and getattr(mesh, "axis_names", ()):
-            return mesh
+    """The mesh recorded by :func:`set_mesh`, or None."""
     return _ACTIVE_MESH
 
 

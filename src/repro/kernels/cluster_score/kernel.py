@@ -27,9 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names this TPUCompilerParams; keep one alias for both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 __all__ = ["cluster_scores_kernel"]
 
 
@@ -92,7 +89,7 @@ def cluster_scores_kernel(
         ],
         out_specs=pl.BlockSpec((block_d, k), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, k), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -20,9 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names this TPUCompilerParams; keep one alias for both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 from repro.kernels.flash_attention.ref import NEG_INF
 
 __all__ = ["flash_attention_kernel"]
@@ -134,7 +131,7 @@ def flash_attention_kernel(
             pltpu.VMEM((tile_q, 1), jnp.float32),
             pltpu.VMEM((tile_q, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -90,8 +90,9 @@ def intersect_members(
       * ``"count"`` — (B,) int32 |short ∩ long| through the members
         probe's count reduction.
 
-    On TPU the Pallas kernel probes the long row's tile directory with a
-    per-tile binary search; elsewhere the pure-jnp reference runs (XLA's
+    On TPU the Pallas kernel compares each short tile with the long
+    tiles of its probe window (a rank count against the long row's tile
+    directory); elsewhere the pure-jnp reference runs (XLA's
     fused searchsorted — the production CPU path), or the kernel in
     interpret mode when ``force_kernel`` (tests).
 

@@ -349,7 +349,6 @@ def _flash_decode(q, k_new, v_new, cache: KVCache, window=None):
     (the naive SPMD schedule all-gathered / replicated it — see
     EXPERIMENTS.md §Perf iteration 2).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = get_active_mesh()
@@ -458,7 +457,7 @@ def _flash_decode(q, k_new, v_new, cache: KVCache, window=None):
             ks2 = vs2 = jnp.zeros((), jnp.float32)
         return out, kc2, vc2, ks2, vs2
 
-    fn = shard_map(
+    fn = jax.shard_map(
         wrapper,
         mesh=mesh,
         in_specs=(
@@ -478,6 +477,7 @@ def _flash_decode(q, k_new, v_new, cache: KVCache, window=None):
             scale_spec if quantized else P(),
             scale_spec if quantized else P(),
         ),
+        check_vma=True,
     )
     out, kc, vc, ks, vs = fn(
         q, k_new, v_new, cache.k, cache.v, ks_in, vs_in, cache.length
@@ -620,7 +620,6 @@ def moe_apply(
 
 
 def _moe_apply_sharded(p, x, top_k, capacity_factor, act, mesh, dp_axes):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     e = p["router"]["kernel"].shape[1]
@@ -690,7 +689,7 @@ def _moe_apply_sharded(p, x, top_k, capacity_factor, act, mesh, dp_axes):
         return jax.lax.psum(out, "model"), aux
 
     gate_arr = p["gate"] if has_gate else p["up"]  # placeholder, unused
-    fn = shard_map(
+    fn = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(
@@ -701,6 +700,7 @@ def _moe_apply_sharded(p, x, top_k, capacity_factor, act, mesh, dp_axes):
             P(dp_spec, None),
         ),
         out_specs=(P(dp_spec, None), P()),
+        check_vma=True,
     )
     return fn(p["router"]["kernel"], p["up"], gate_arr, p["down"], x)
 

@@ -384,12 +384,11 @@ class SearchService:
             # drops them by construction instead of crediting query 0.
             rq = jnp.pad(rq, (0, pad), constant_values=nq)
             ra = jnp.pad(ra, (0, pad), constant_values=0)
-        from jax.experimental.shard_map import shard_map
-
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda s, r, a: jax.lax.psum(local(s, r, a), dp_axes),
             mesh=mesh,
             in_specs=(tuple(P(dp, None) for _ in segs), P(dp), P(dp)),
             out_specs=P(),
+            check_vma=True,
         )
         return fn(segs, rq, ra)
