@@ -7,7 +7,8 @@ enforces mechanically:
   run by ``tools/seclint.py`` and the CI ``lint-static`` job.
 * :mod:`repro.analysis.runtime` — the ``REPRO_DEBUG`` gate behind the
   structural ``validate()`` methods on ``HierIndex`` / ``SegmentPlan`` /
-  ``DeviceIndex`` / ``ShardedDeviceIndex``.
+  ``DeviceIndex`` / ``ShardedDeviceIndex``, and ``span``, the timed
+  profiler span of the serving path.
 * :mod:`repro.analysis.sanitize` — the pytest sanitize mode: implicit
   transfer guard + jit compile counter.
 

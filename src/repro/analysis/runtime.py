@@ -1,4 +1,4 @@
-"""The `REPRO_DEBUG` gate for the runtime validation head.
+"""Runtime helpers: the `REPRO_DEBUG` validation gate and timed spans.
 
 Structural ``validate()`` methods (monotone CSR pointers, nested level
 ranges, sorted postings, shard partition exactness — see
@@ -12,14 +12,20 @@ time on large indexes, so production builds skip them.  They run when
 
 Call sites gate through :func:`maybe_validate` so the fast path stays a
 single dict lookup.
+
+:func:`span` is the one timing primitive of the serving path: a profiler
+trace annotation whose wall-clock duration also lands in an ``info``
+dict, so a ``t_*_s`` key and its trace span are the same interval.
 """
 
 from __future__ import annotations
 
 import contextlib
 import os
+import time
+from typing import Optional
 
-__all__ = ["debug_enabled", "force_debug", "maybe_validate"]
+__all__ = ["debug_enabled", "force_debug", "maybe_validate", "span"]
 
 _FALSY = ("", "0", "false", "False", "no")
 
@@ -53,3 +59,20 @@ def maybe_validate(obj):
     if debug_enabled():
         obj.validate()
     return obj
+
+
+@contextlib.contextmanager
+def span(name: str, info: Optional[dict] = None, key: Optional[str] = None, **args):
+    """A ``jax.profiler.TraceAnnotation`` named ``name`` (``args`` become
+    its trace arguments) that also adds its ``time.perf_counter``
+    duration to ``info[key]``.  Spans sharing a key sum, so consecutive
+    spans time one interval together.  With no profiler running the
+    annotation costs one check.  (jax is imported here, not at module
+    level, so the lint head stays importable without it.)"""
+    from jax.profiler import TraceAnnotation
+
+    with TraceAnnotation(name, **args):
+        t0 = time.perf_counter()
+        yield
+        if key is not None:
+            info[key] = info.get(key, 0.0) + (time.perf_counter() - t0)

@@ -48,14 +48,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.analysis.runtime import maybe_validate
+from repro.analysis.runtime import maybe_validate, span
 from repro.core.batched_query import _ragged_gather, _ragged_indices
 from repro.core.hier_index import HierIndex, as_hier, shard_tops
 from repro.core.queries import as_queries
@@ -66,7 +65,6 @@ __all__ = [
     "DeviceLevel",
     "device_index",
     "lower_plan",
-    "device_fold",
     "device_counts",
     "fold_cache_size",
     "plan_shape_key",
@@ -404,39 +402,50 @@ def _fold_core(
 ):
     """The whole multi-stage fold — the traced body shared by the
     single-device jit (:func:`_fused_fold`) and the per-shard program the
-    sharded path runs under ``shard_map``.  Returns per-query counts
-    (quantized width — the caller slices), per-stage survivor totals
-    (live active cells entering each stage), and — when
-    ``return_members`` — the final cell vector (PAD holes in place).
+    sharded path runs under ``shard_map``.  Returns ``(tally,
+    members)``: ``tally`` is one int vector, the per-query counts
+    (quantized width ``n_queries_pad`` — the caller slices) followed by
+    the per-stage survivor totals (live active cells entering each
+    stage), so a batch reads back (and the sharded path all-reduces) one
+    array; ``members`` — when ``return_members`` — is the final cell
+    vector (PAD holes in place).
 
     Stage s filters only the cells whose group is still active
     (``arity > s``); finished groups and quantization-pad cells pass
     through untouched, so every shape here is a quantized static — the
     jit cache key is (shapes, group_width, stage_iters, n_queries_pad),
     shared by all batches of similar size.
+
+    Named scopes give the device ops stable names in the profiler's
+    trace, whatever HLO names the compiler picks: ``seclud.fold/gather``
+    (the cells' first values), ``seclud.fold/stage{s}`` (stage s's
+    binary search) and ``seclud.fold/count`` (the ``segment_sum``).
     """
     n = post_docs.shape[0]
-    cell_post, cell_group, cell_query, cell_arity = (
-        cells[0], cells[1], cells[2], cells[3],
-    )
-    cur = post_docs[jnp.clip(cell_post, 0, n - 1)]
-    cur = jnp.where(cell_post != PAD, cur, PAD)
-    entering = []
-    for s, iters in enumerate(stage_iters, start=1):
-        seg = stage_seg[:, (s - 1) * group_width : s * group_width]
-        lo = seg[0][cell_group]
-        hi = lo + seg[1][cell_group]
-        act = cell_arity > s
-        entering.append(((cur != PAD) & act).sum())
-        found = _search_segments(post_docs, cur, lo, hi, iters)
-        cur = jnp.where(act & ~found, PAD, cur)
-    counts = jax.ops.segment_sum(
-        (cur != PAD).astype(jnp.int32), cell_query, num_segments=n_queries_pad
-    )
-    entering_arr = (
-        jnp.stack(entering) if entering else jnp.zeros(0, jnp.int32)
-    )
-    return counts, entering_arr, (cur if return_members else None)
+    with jax.named_scope("seclud.fold"):
+        cell_post, cell_group, cell_query, cell_arity = (
+            cells[0], cells[1], cells[2], cells[3],
+        )
+        with jax.named_scope("gather"):
+            cur = post_docs[jnp.clip(cell_post, 0, n - 1)]
+            cur = jnp.where(cell_post != PAD, cur, PAD)
+        entering = []
+        for s, iters in enumerate(stage_iters, start=1):
+            with jax.named_scope(f"stage{s}"):
+                seg = stage_seg[:, (s - 1) * group_width : s * group_width]
+                lo = seg[0][cell_group]
+                hi = lo + seg[1][cell_group]
+                act = cell_arity > s
+                entering.append(((cur != PAD) & act).sum())
+                found = _search_segments(post_docs, cur, lo, hi, iters)
+                cur = jnp.where(act & ~found, PAD, cur)
+        with jax.named_scope("count"):
+            counts = jax.ops.segment_sum(
+                (cur != PAD).astype(jnp.int32), cell_query, num_segments=n_queries_pad
+            )
+        tally = jnp.concatenate([counts, jnp.stack(entering).astype(counts.dtype)]
+                                if entering else [counts])
+    return tally, (cur if return_members else None)
 
 
 _fused_fold = functools.partial(
@@ -448,26 +457,6 @@ _fused_fold = functools.partial(
         "return_members",
     ),
 )(_fold_core)
-
-
-def device_fold(
-    dindex: DeviceIndex,
-    lowered: LoweredPlan,
-    return_members: bool = False,
-):
-    """Run the fused fold of a lowered plan against a resident index.
-    Returns ``(counts, entering, members)`` — device arrays; ``counts``
-    has the quantized ``n_queries_pad`` width and ``members`` is None
-    unless requested."""
-    return _fused_fold(
-        dindex.post_docs,
-        jax.device_put(lowered.cells),
-        jax.device_put(lowered.stage_seg),
-        group_width=lowered.group_width,
-        stage_iters=lowered.stage_iters,
-        n_queries_pad=lowered.n_queries_pad,
-        return_members=return_members,
-    )
 
 
 # ----------------------------------------------------------------------
@@ -610,10 +599,24 @@ def _stage_info(lowered: LoweredPlan, entering: np.ndarray) -> List[Dict[str, fl
                 "long_cells": long_cells,
                 "padding_overhead": (carried + long_cells)
                 / max(live + long_cells, 1.0),
-                "kernel_calls": 0.0,  # fused: no per-stage dispatch at all
             }
         )
     return stages
+
+
+# ``info`` of a batch with no (query, cluster) pair: nothing reaches the
+# device.
+_EMPTY_INFO = {
+    "n_pairs": 0.0,
+    "n_kernel_calls": 0.0,
+    "padding_overhead": 1.0,
+    "cells": 0.0,
+    "cells_true": 0.0,
+    "upload_bytes": 0.0,
+    "t_lower_s": 0.0,
+    "t_fold_s": 0.0,
+    "jit_compiles": 0.0,
+}
 
 
 def device_counts(
@@ -637,23 +640,29 @@ def device_counts(
     true cells; the long sides are probed in place and contribute zero
     padding), ``occupancy`` (live survivor cells / cells carried across
     all stages — the masked-execution analogue of pad waste), and
-    ``stages`` (per-stage attribution dicts).  Per-call timing hooks for
-    the serving loop ride along: ``t_plan_s`` / ``t_lower_s`` /
-    ``t_fold_s`` split the call into host planning, lowering, and the
-    fused dispatch (incl. the device round-trip); ``jit_compiles`` is
-    the fold-cache growth this call caused (0 on every warm path).
+    ``stages`` (per-stage attribution dicts).  The cells the batch
+    uploads: ``cells`` (padded cells the fold carries), ``cells_true``
+    and ``upload_bytes`` (the per-batch ``device_put`` bytes).  Per-call
+    timing hooks for the serving loop ride along: ``t_plan_s`` /
+    ``t_lower_s`` / ``t_fold_s`` split the call into host planning,
+    lowering, and the fused dispatch (upload, dispatch and readback: the
+    device round-trip); each is the duration of the profiler span of the
+    same interval (``seclud.plan``, ``seclud.lower``, then
+    ``seclud.upload`` + ``seclud.dispatch`` + ``seclud.readback``).
+    ``jit_compiles`` is the fold-cache growth this call caused (0 on
+    every warm path).
     """
     from repro.core.batched_query import plan_segment_pairs
 
-    t0 = time.perf_counter()
-    cq = as_queries(queries)
-    if dindex is None:
-        dindex = device_index(cidx)
-    if plan is None:
-        # The device path needs the segment layout, not the paper's work
-        # metric — plan without the probe/scan accounting.
-        plan = plan_segment_pairs(dindex.host, cq, track_work=False)
-    t_plan = time.perf_counter() - t0
+    info: Dict[str, object] = {}
+    with span("seclud.plan", info, "t_plan_s"):
+        cq = as_queries(queries)
+        if dindex is None:
+            dindex = device_index(cidx)
+        if plan is None:
+            # The device path needs the segment layout, not the paper's
+            # work metric — plan without the probe/scan accounting.
+            plan = plan_segment_pairs(dindex.host, cq, track_work=False)
     if fault_hook is not None:
         # Injection point of the chaos harness (repro.serve.faults): a
         # scheduled fault raises here, inside the real dispatch path —
@@ -662,50 +671,49 @@ def device_counts(
         fault_hook.on_dispatch(n_shards=1)
     if plan.n_pairs == 0:
         counts = np.zeros(plan.n_queries, np.int64)
-        info = {
-            "n_pairs": 0.0,
-            "n_kernel_calls": 0.0,
-            "padding_overhead": 1.0,
-            "occupancy": 1.0,
-            "stages": [],
-            "t_plan_s": t_plan,
-            "t_lower_s": 0.0,
-            "t_fold_s": 0.0,
-            "jit_compiles": 0.0,
-        }
+        info.update(_EMPTY_INFO, occupancy=1.0, stages=[])
         if return_docs:
             return counts, np.empty(0, np.int32), info
         return counts, info
 
-    t1 = time.perf_counter()
-    lowered = lower_plan(plan)
-    t_lower = time.perf_counter() - t1
+    with span("seclud.lower", info, "t_lower_s"):
+        lowered = lower_plan(plan)
     cache_before = fold_cache_size()
-    t2 = time.perf_counter()
-    counts_d, entering_d, members_d = device_fold(
-        dindex, lowered, return_members=return_docs
-    )
-    counts = jax.device_get(counts_d)[: lowered.n_queries].astype(np.int64)
-    entering = jax.device_get(entering_d)
-    t_fold = time.perf_counter() - t2
+    with span("seclud.upload", info, "t_fold_s"):
+        cells_d = jax.device_put(lowered.cells)
+        stage_seg_d = jax.device_put(lowered.stage_seg)
+    with span("seclud.dispatch", info, "t_fold_s"):
+        tally_d, members_d = _fused_fold(
+            dindex.post_docs,
+            cells_d,
+            stage_seg_d,
+            group_width=lowered.group_width,
+            stage_iters=lowered.stage_iters,
+            n_queries_pad=lowered.n_queries_pad,
+            return_members=return_docs,
+        )
+    with span("seclud.readback", info, "t_fold_s"):
+        tally = jax.device_get(tally_d)
+        counts = tally[: lowered.n_queries].astype(np.int64)
+    entering = tally[lowered.n_queries_pad :]
 
     stages = _stage_info(lowered, entering)
     true_cells = float(lowered.n_cells_true)
     long_cells = float(sum(s["long_cells"] for s in stages))
     carried = float(lowered.n_cells) + sum(s["cur_cells"] for s in stages)
     live = true_cells + sum(s["cur_live"] for s in stages)
-    info = {
-        "n_pairs": float(plan.n_pairs),
-        "n_kernel_calls": 1.0,
-        "padding_overhead": (float(lowered.n_cells) + long_cells)
+    info.update(
+        n_pairs=float(plan.n_pairs),
+        n_kernel_calls=1.0,
+        padding_overhead=(float(lowered.n_cells) + long_cells)
         / max(true_cells + long_cells, 1.0),
-        "occupancy": live / max(carried, 1.0),
-        "stages": stages,
-        "t_plan_s": t_plan,
-        "t_lower_s": t_lower,
-        "t_fold_s": t_fold,
-        "jit_compiles": float(fold_cache_size() - cache_before),
-    }
+        occupancy=live / max(carried, 1.0),
+        stages=stages,
+        cells=float(lowered.n_cells),
+        cells_true=true_cells,
+        upload_bytes=float(lowered.cells.nbytes + lowered.stage_seg.nbytes),
+        jit_compiles=float(fold_cache_size() - cache_before),
+    )
     if not return_docs:
         return counts, info
 
@@ -1059,7 +1067,7 @@ def _build_sharded_fold(
     cells_spec, seg_spec = sh.plan_specs(mesh)
 
     def body(post_docs, cells, stage_seg):
-        counts, entering, cur = _fold_core(
+        tally, cur = _fold_core(
             post_docs[0],
             cells[0],
             stage_seg[0],
@@ -1068,14 +1076,12 @@ def _build_sharded_fold(
             n_queries_pad=n_queries_pad,
             return_members=return_members,
         )
-        counts = jax.lax.psum(counts, dp_axes)
-        if stage_iters:
-            entering = jax.lax.psum(entering, dp_axes)
+        tally = jax.lax.psum(tally, dp_axes)
         if return_members:
-            return counts, entering, cur[None]
-        return counts, entering
+            return tally, cur[None]
+        return (tally,)
 
-    out_specs = (P(), P())
+    out_specs = (P(),)
     if return_members:
         out_specs = out_specs + (sh.postings_spec(mesh),)
     # check_vma=False: the psum over the data axes is what makes the
@@ -1114,23 +1120,29 @@ def sharded_device_counts(
     ``agg_throughput`` (total true cells / max per-shard true
     cells — the deterministic load-balance speedup bound) and
     ``load_balance`` (= agg_throughput / n_shards, the scaling
-    efficiency).  ``fault_hook`` is the chaos harness's injection point
-    (:mod:`repro.serve.faults`): called inside the dispatch path, where
-    it may raise scheduled faults and perturb ``shard_times``."""
+    efficiency).  ``cells`` counts every shard's padded cells (``n_shards
+    × n_cells``); ``cells_true``, ``upload_bytes`` and the ``t_*_s``
+    spans are as in :func:`device_counts`.  ``fault_hook`` is the chaos
+    harness's injection point (:mod:`repro.serve.faults`): called inside
+    the dispatch path, where it may raise scheduled faults and perturb
+    ``shard_times``."""
+    from jax.sharding import NamedSharding
+
     from repro.analysis.sanitize import jit_cache_size
     from repro.core.batched_query import plan_segment_pairs
+    from repro.dist import sharding as sh
 
-    t0 = time.perf_counter()
-    cq = as_queries(queries)
-    if sidx is None:
-        sidx = (
-            cidx
-            if isinstance(cidx, ShardedDeviceIndex)
-            else sharded_device_index(cidx)
-        )
-    if plan is None:
-        plan = plan_segment_pairs(sidx.host, cq, track_work=False)
-    t_plan = time.perf_counter() - t0
+    info: Dict[str, object] = {}
+    with span("seclud.plan", info, "t_plan_s"):
+        cq = as_queries(queries)
+        if sidx is None:
+            sidx = (
+                cidx
+                if isinstance(cidx, ShardedDeviceIndex)
+                else sharded_device_index(cidx)
+            )
+        if plan is None:
+            plan = plan_segment_pairs(sidx.host, cq, track_work=False)
     if fault_hook is not None:
         # Chaos-harness injection point (repro.serve.faults): scheduled
         # faults raise here, inside the real sharded dispatch path; the
@@ -1139,53 +1151,39 @@ def sharded_device_counts(
         fault_hook.on_dispatch(n_shards=sidx.n_shards)
     if plan.n_pairs == 0:
         counts = np.zeros(plan.n_queries, np.int64)
-        info = {
-            "n_pairs": 0.0,
-            "n_kernel_calls": 0.0,
-            "n_shards": float(sidx.n_shards),
-            "shards_touched": 0.0,
-            "shard_cells": [0.0] * sidx.n_shards,
-            "shard_times": [0.0] * sidx.n_shards,
-            "agg_throughput": 1.0,
-            "load_balance": 1.0 / max(sidx.n_shards, 1),
-            "padding_overhead": 1.0,
-            "t_plan_s": t_plan,
-            "t_lower_s": 0.0,
-            "t_fold_s": 0.0,
-            "jit_compiles": 0.0,
-        }
+        info.update(
+            _EMPTY_INFO,
+            n_shards=float(sidx.n_shards),
+            shards_touched=0.0,
+            shard_cells=[0.0] * sidx.n_shards,
+            shard_times=[0.0] * sidx.n_shards,
+            agg_throughput=1.0,
+            load_balance=1.0 / max(sidx.n_shards, 1),
+        )
         if return_docs:
             return counts, np.empty(0, np.int32), info
         return counts, info
 
-    t1 = time.perf_counter()
-    lowered = lower_plan_sharded(plan, sidx)
-    fold = _build_sharded_fold(
-        sidx.mesh,
-        lowered.group_width,
-        lowered.stage_iters,
-        lowered.n_queries_pad,
-        bool(return_docs),
-    )
-    t_lower = time.perf_counter() - t1
+    with span("seclud.lower", info, "t_lower_s"):
+        lowered = lower_plan_sharded(plan, sidx)
+        fold = _build_sharded_fold(
+            sidx.mesh,
+            lowered.group_width,
+            lowered.stage_iters,
+            lowered.n_queries_pad,
+            bool(return_docs),
+        )
     cache_before = jit_cache_size(fold)
     # Explicit per-batch upload, pre-placed shard-per-row so the jit
     # never reshards (and never transfers implicitly).
-    from jax.sharding import NamedSharding
-
-    from repro.dist import sharding as sh
-
-    t2 = time.perf_counter()
-    cells_spec, seg_spec = sh.plan_specs(sidx.mesh)
-    out = fold(
-        sidx.post_docs,
-        jax.device_put(lowered.cells, NamedSharding(sidx.mesh, cells_spec)),
-        jax.device_put(
-            lowered.stage_seg, NamedSharding(sidx.mesh, seg_spec)
-        ),
-    )
-    counts = jax.device_get(out[0])[: lowered.n_queries].astype(np.int64)
-    t_fold = time.perf_counter() - t2
+    with span("seclud.upload", info, "t_fold_s"):
+        cells_spec, seg_spec = sh.plan_specs(sidx.mesh)
+        cells_d = jax.device_put(lowered.cells, NamedSharding(sidx.mesh, cells_spec))
+        stage_seg_d = jax.device_put(lowered.stage_seg, NamedSharding(sidx.mesh, seg_spec))
+    with span("seclud.dispatch", info, "t_fold_s"):
+        out = fold(sidx.post_docs, cells_d, stage_seg_d)
+    with span("seclud.readback", info, "t_fold_s"):
+        counts = jax.device_get(out[0])[: lowered.n_queries].astype(np.int64)
     compiles = float(jit_cache_size(fold) - cache_before)
     total_true = float(lowered.n_cells_true.sum())
     max_true = float(lowered.n_cells_true.max())
@@ -1195,26 +1193,25 @@ def sharded_device_counts(
     # the honest per-shard attribution on a single-process rig is the
     # fold time itself, equal across shards; a real straggler (or an
     # injected one) shows up as that shard's entry inflating.
-    shard_times = np.full(lowered.n_shards, t_fold, np.float64)
+    shard_times = np.full(lowered.n_shards, info["t_fold_s"], np.float64)
     if fault_hook is not None:
         shard_times = fault_hook.perturb_shard_times(shard_times)
-    info = {
-        "n_pairs": float(plan.n_pairs),
-        "n_kernel_calls": 1.0,
-        "n_shards": float(lowered.n_shards),
-        "shards_touched": float(lowered.shards_touched),
-        "shard_cells": lowered.n_cells_true.astype(float).tolist(),
-        "shard_times": [float(x) for x in shard_times],
-        "agg_throughput": total_true / max(max_true, 1.0),
-        "load_balance": total_true
-        / max(lowered.n_shards * max_true, 1.0),
-        "padding_overhead": float(lowered.n_shards * lowered.n_cells)
+    info.update(
+        n_pairs=float(plan.n_pairs),
+        n_kernel_calls=1.0,
+        n_shards=float(lowered.n_shards),
+        shards_touched=float(lowered.shards_touched),
+        shard_cells=lowered.n_cells_true.astype(float).tolist(),
+        shard_times=[float(x) for x in shard_times],
+        agg_throughput=total_true / max(max_true, 1.0),
+        load_balance=total_true / max(lowered.n_shards * max_true, 1.0),
+        padding_overhead=float(lowered.n_shards * lowered.n_cells)
         / max(total_true, 1.0),
-        "t_plan_s": t_plan,
-        "t_lower_s": t_lower,
-        "t_fold_s": t_fold,
-        "jit_compiles": compiles,
-    }
+        cells=float(lowered.n_shards * lowered.n_cells),
+        cells_true=total_true,
+        upload_bytes=float(lowered.cells.nbytes + lowered.stage_seg.nbytes),
+        jit_compiles=compiles,
+    )
     if not return_docs:
         return counts, info
 
@@ -1222,7 +1219,7 @@ def sharded_device_counts(
     # sit contiguously inside its owning shard's row; gathering rows in
     # group order and dropping PAD holes restores exactly the
     # single-device (and host-loop) doc array.
-    members = jax.device_get(out[2]).reshape(-1)
+    members = jax.device_get(out[1]).reshape(-1)
     starts = lowered.grp_shard * lowered.n_cells + lowered.grp_off
     orig_cells = _ragged_gather(members, starts, lowered.grp_cnt)
     docs = orig_cells[orig_cells != PAD].astype(np.int32)

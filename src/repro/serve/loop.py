@@ -19,8 +19,10 @@ batch throughput.  This module turns the fused device engine
   sealed batch as ONE fused engine call (``serve_counts_device`` /
   ``sharded_device_counts``), resolving per-request futures with the
   counts, and accounting every request (enqueue -> dispatch -> reply)
-  and every batch (size, queue depth, device time, jit-cache growth via
-  ``analysis.sanitize.jit_cache_size``) in :class:`ServeStats`.
+  and every batch (size, queue depth, jit-cache growth via
+  ``analysis.sanitize.jit_cache_size``) in :class:`ServeStats`.  Each
+  batch is one ``seclud.batch`` profiler span (``seclud.seal``, the
+  engine's own spans, ``seclud.reply`` nested inside it).
 
 * ``AsyncServingLoop.prewarm`` — compile the quantized ``lower_plan``
   shape grid at startup (:func:`repro.core.device_engine.prewarm`), so
@@ -44,6 +46,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.analysis.runtime import span
 from repro.core.queries import ConjunctiveQueries
 
 __all__ = [
@@ -130,8 +133,8 @@ class ServeStats:
     Requests carry (enqueue, dispatch, reply) timestamps — latency is
     reply minus enqueue, the number the SLO is written against — plus a
     per-request ``outcome`` ("ok" or "shed").  Batches carry size, queue
-    depth at seal, device time, the jit-cache growth their dispatch
-    caused (0 on every warm batch), and the resilience accounting:
+    depth at seal, the jit-cache growth their dispatch caused (0 on
+    every warm batch), and the resilience accounting:
     dispatch ``attempts`` spent and the degradation ``level`` the batch
     was served at (``repro.serve.resilience.LEVELS`` ladder).
     """
@@ -143,7 +146,6 @@ class ServeStats:
         self.t_reply: List[float] = []
         self.outcomes: List[str] = []  # per request: "ok" | "shed"
         self.batch_sizes: List[int] = []
-        self.batch_device_s: List[float] = []
         self.batch_compiles: List[int] = []
         self.queue_depths: List[int] = []
         self.batch_attempts: List[int] = []
@@ -155,7 +157,6 @@ class ServeStats:
         t_enqueue: Sequence[float],
         t_dispatch: float,
         t_reply: float,
-        device_s: float,
         jit_compiles: int,
         queue_depth: int,
         attempts: int = 1,
@@ -166,7 +167,6 @@ class ServeStats:
         self.t_reply.extend([float(t_reply)] * len(t_enqueue))
         self.outcomes.extend(["ok"] * len(t_enqueue))
         self.batch_sizes.append(len(t_enqueue))
-        self.batch_device_s.append(float(device_s))
         self.batch_compiles.append(int(jit_compiles))
         self.queue_depths.append(int(queue_depth))
         self.batch_attempts.append(int(attempts))
@@ -473,31 +473,37 @@ class AsyncServingLoop:
             self._dispatch(batch)
 
     def _dispatch(self, batch) -> None:
-        terms, futs, t_enq = zip(*batch, strict=True)
-        cq = ConjunctiveQueries.from_lists(list(terms))
         depth = len(self._pending)  # what the dispatch leaves queued
-        before = self._probe()
-        t_d = time.perf_counter()
-        if self._dispatcher is not None:
-            if self._injector is not None:
-                self._injector.begin_batch()
-            counts, _info, outcome = self._dispatcher.dispatch(cq)
-            attempts, level = outcome.attempts, outcome.level
-        else:
-            out = self._engine(cq)
-            counts = np.asarray(out[0] if isinstance(out, tuple) else out)
-            attempts, level = 1, "device"
-        t_r = time.perf_counter()
-        self.stats.add_batch(
-            t_enq,
-            t_d,
-            t_r,
-            device_s=t_r - t_d,
-            jit_compiles=self._probe() - before,
-            queue_depth=depth,
-            attempts=attempts,
-            level=level,
-        )
-        for fut, c in zip(futs, counts, strict=True):
-            if not fut.done():
-                fut.set_result(int(c))
+        # The span's ``batch`` argument is the batch's ordinal; every span
+        # nested inside it (the engine's plan, lower, upload, dispatch and
+        # readback) belongs to that batch.
+        with span("seclud.batch", batch=self.stats.n_batches, size=len(batch),
+                  queue_depth=depth):
+            with span("seclud.seal"):
+                terms, futs, t_enq = zip(*batch, strict=True)
+                cq = ConjunctiveQueries.from_lists(list(terms))
+            before = self._probe()
+            t_d = time.perf_counter()
+            if self._dispatcher is not None:
+                if self._injector is not None:
+                    self._injector.begin_batch()
+                counts, _info, outcome = self._dispatcher.dispatch(cq)
+                attempts, level = outcome.attempts, outcome.level
+            else:
+                out = self._engine(cq)
+                counts = np.asarray(out[0] if isinstance(out, tuple) else out)
+                attempts, level = 1, "device"
+            t_r = time.perf_counter()
+            with span("seclud.reply"):
+                self.stats.add_batch(
+                    t_enq,
+                    t_d,
+                    t_r,
+                    jit_compiles=self._probe() - before,
+                    queue_depth=depth,
+                    attempts=attempts,
+                    level=level,
+                )
+                for fut, c in zip(futs, counts, strict=True):
+                    if not fut.done():
+                        fut.set_result(int(c))

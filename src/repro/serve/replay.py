@@ -230,7 +230,6 @@ def _sealed_loop(
             arrivals[i:j],
             dispatch,
             reply,
-            device_s=service_s,
             jit_compiles=probe() - before,
             queue_depth=depth,
             attempts=attempts,

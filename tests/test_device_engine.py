@@ -249,7 +249,7 @@ def test_device_counts_info_contract(rng):
     assert 0.0 < info["occupancy"] <= 1.0
     for s in info["stages"]:
         assert {"stage", "cur_cells", "cur_live", "long_cells",
-                "padding_overhead", "kernel_calls"} <= set(s)
+                "padding_overhead"} <= set(s)
         assert s["padding_overhead"] >= 1.0 or s["long_cells"] == 0
         assert s["cur_live"] <= s["cur_cells"]
 
