@@ -108,8 +108,8 @@ SENTINEL_PATTERNS = (
 _NP_ALIASES = {"np", "numpy", "onp"}
 
 # Parameter annotations that mark a host scalar/static, exempt from
-# taint in transitively-traced helpers (e.g. ``iters: int`` of the
-# binary search, ``stage_iters: Tuple[int, ...]`` of the fold).
+# taint in transitively-traced helpers (e.g. ``group_width: int`` and
+# ``stage_levels: Tuple[int, ...]`` of the fold).
 _SCALAR_ANNOTATIONS = {"int", "bool", "float", "str"}
 _SCALAR_ANNOTATION_PREFIXES = ("Tuple", "tuple", "Sequence", "List", "list")
 

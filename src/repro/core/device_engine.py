@@ -24,24 +24,27 @@ engine at arity >= 3.  This module replaces all of it with three pieces:
   (``pad-to-bin-max`` degenerates to pad-to-tail here; the pow2-per-pair
   scheme and its 1.5–1.9x overhead are gone).  Every shape entering the
   jit — cell count, per-stage group width, query count — is rounded up
-  at ~1/8 granularity and the per-stage binary-search depths to even
-  values, so batches of similar size share one compiled executable
-  instead of retracing per batch.
+  at ~1/8 granularity, and each stage's search depth is the number of
+  128-ary levels that covers its longest segment, so batches of similar
+  size share one compiled executable instead of retracing per batch.
 
 * ``_fused_fold`` — ONE ``jax.jit`` call executes every chain stage:
-  stage s binary-searches the surviving cells of the still-active groups
-  (``arity > s``, a per-cell mask) into their group's rank-s segment
-  (``lo/hi`` bounds per cell, ``lax.fori_loop`` over the static bit
-  length of the stage's longest segment); misses are masked to PAD in
-  place — intermediate survivor lists never leave device memory.  A
-  final ``segment_sum`` maps cells to per-query counts.  Only the counts
-  (and, on request, the member doc ids) return to host.
+  stage s searches the surviving cells of the still-active groups
+  (``arity > s``, a per-cell mask) in their group's rank-s segment
+  (``lo/hi`` bounds per cell) by a 128-ary descent over fences of the
+  resident postings: each level reads whole 128-lane rows and counts the
+  entries at or below the cell's value, so a cell costs a few row reads
+  instead of a chain of dependent scalar gathers; misses are masked to
+  PAD in place — intermediate survivor lists never leave device memory.
+  A final ``segment_sum`` maps cells to per-query counts.  Only the
+  counts (and, on request, the member doc ids) return to host.
 
 Exactness: counts (and docs) are bit-identical to looping
 ``HierIndex.query`` / ``ClusterIndex.query`` at every depth and arity —
-the plan already encodes the descent, and masked binary-search
-intersection is exact set intersection.  On CPU the same fused fold runs
-through XLA (the jnp path IS the fallback); no TPU is required.
+the plan already encodes the descent, and a search masked to each
+cell's own segment is exact set intersection.  On CPU the same fused
+fold runs through XLA (the jnp path IS the fallback); no TPU is
+required.
 """
 
 from __future__ import annotations
@@ -79,6 +82,13 @@ __all__ = [
 ]
 
 _CELL_ALIGN = 8  # flat cell vector tail alignment (the only padding left)
+_LANES = 128  # one row the segment search reads: 128 int32 lanes, 512 bytes
+# Resident postings and fences pad to whole (8, 128) int32 tiles, so the
+# fold's (rows, 128) view of them is a bitcast, not a copy.
+_TILE = 8 * _LANES
+# Cells the segment search carries per loop step: bounds the rows one
+# step gathers (a (block, 256) int32 slab at the top level, 8 MB).
+_SEARCH_BLOCK = 8192
 
 
 def _quantize(n: int) -> int:
@@ -88,6 +98,50 @@ def _quantize(n: int) -> int:
     ``padding_overhead``; without it every batch would retrace."""
     g = max(_CELL_ALIGN, 1 << max(int(max(n, 1) - 1).bit_length() - 3, 0))
     return -(-max(n, 1) // g) * g
+
+
+def _search_levels(n: int) -> int:
+    """The 128-ary levels that cover a segment of ``n`` postings: the
+    least ``L >= 1`` with ``n <= 128**L``.  Level 0 is the postings' own
+    rows; level j >= 1 is fence j."""
+    levels = 1
+    while _LANES**levels < n:
+        levels += 1
+    return levels
+
+
+def _pad_tiles(a: np.ndarray) -> np.ndarray:
+    """``a`` as int32, padded with PAD to whole (8, 128) tiles."""
+    out = np.full(-(-max(len(a), 1) // _TILE) * _TILE, PAD, np.int32)
+    out[: len(a)] = a
+    return out
+
+
+def _fences(post_docs: np.ndarray, levels: int) -> Tuple[np.ndarray, ...]:
+    """Fences 1 .. ``levels - 1`` of the tile-padded ``post_docs``:
+    ``fence_j[i] = post_docs[i * 128**j]``, each padded to whole tiles."""
+    return tuple(_pad_tiles(post_docs[:: _LANES**j]) for j in range(1, levels))
+
+
+def _check_fences(post_docs: np.ndarray, fences, what: str) -> None:
+    """Every fence entry equals ``post_docs`` at its aligned position
+    (PAD past the end) — the invariant the segment search's exactness
+    rests on."""
+    if len(post_docs) % _TILE:
+        raise ValueError(f"{what}: postings not padded to whole (8, 128) tiles")
+    for j, fence in enumerate(fences, start=1):
+        fence = np.asarray(fence)
+        pos = np.arange(len(fence), dtype=np.int64) * _LANES**j
+        inside = pos < len(post_docs)
+        if (
+            len(fence) % _TILE
+            or (fence[inside] != post_docs[pos[inside]]).any()
+            or (fence[~inside] != PAD).any()
+        ):
+            raise ValueError(
+                f"{what}: fence {j} disagrees with the postings at its "
+                "aligned positions — the segment search would miss matches"
+            )
 
 
 # ----------------------------------------------------------------------
@@ -110,19 +164,23 @@ class DeviceLevel:
 class DeviceIndex:
     """The whole hierarchical index resident on device, uploaded once.
 
-    ``post_docs`` is the array every fold probes; the level CSRs ride
+    ``post_docs`` is the array every fold probes, padded with PAD to whole
+    (8, 128) tiles; ``fences`` are its 128-ary fences (fence j holds every
+    ``128**j``-th posting), ``search_levels - 1`` of them, so the fold's
+    segment search covers the longest posting list.  The level CSRs ride
     along so any future device-side descent finds them already resident.
     ``host`` is the host-side :class:`HierIndex` the planner runs on —
     the two views share nothing at execution time (the fold touches only
     device arrays) but stay paired so callers can't mix indexes.
     """
 
-    post_docs: object  # jax.Array (n_postings,) int32
+    post_docs: object  # jax.Array (n_postings padded to tiles,) int32
     post_ptr: object  # jax.Array (n_terms + 1,) int64
     levels: Tuple[DeviceLevel, ...]
     n_docs: int
     n_postings: int
-    search_iters: int  # static: bit length of the longest posting list
+    fences: Tuple[object, ...]  # jax.Arrays: fence j at index j - 1
+    search_levels: int  # static: 128-ary levels covering the longest list
     host: HierIndex
 
     @property
@@ -130,6 +188,7 @@ class DeviceIndex:
         """Resident bytes (post_docs + ptr + level CSRs) — what upload
         amortizes over every subsequent batch."""
         total = int(self.post_docs.nbytes) + int(self.post_ptr.nbytes)
+        total += sum(int(f.nbytes) for f in self.fences)
         for lev in self.levels:
             total += sum(
                 int(getattr(lev, f).nbytes)
@@ -143,16 +202,18 @@ class DeviceIndex:
 
         * ``post_ptr`` is a monotone CSR spanning the posting array;
         * postings are strictly increasing inside every term segment —
-          the binary search (:func:`_search_segments`) is only exact on
+          the segment search (:func:`_search_segments`) is only exact on
           sorted, duplicate-free segments;
         * every level CSR is monotone with in-bounds nested segments;
-        * ``search_iters`` covers the longest posting list.
+        * ``search_levels`` covers the longest posting list, and every
+          fence entry equals the posting at its aligned position.
         """
         post_ptr = jax.device_get(self.post_ptr)
-        post_docs = jax.device_get(self.post_docs)
+        padded = jax.device_get(self.post_docs)
         n_post = self.n_postings
-        if len(post_docs) != n_post:
-            raise ValueError("DeviceIndex: post_docs length != n_postings")
+        if len(padded) < n_post or (padded[n_post:] != PAD).any():
+            raise ValueError("DeviceIndex: post_docs must be n_postings then PAD")
+        post_docs = padded[:n_post]
         if post_ptr[0] != 0 or post_ptr[-1] != n_post:
             raise ValueError("DeviceIndex: post_ptr must span [0, n_postings]")
         if (np.diff(post_ptr) < 0).any():
@@ -172,11 +233,15 @@ class DeviceIndex:
                 )
         lens = np.diff(post_ptr)
         max_len = int(lens.max()) if len(lens) else 0
-        if self.search_iters < max(max_len.bit_length(), 1):
+        if self.search_levels < _search_levels(max_len) or len(
+            self.fences
+        ) != self.search_levels - 1:
             raise ValueError(
-                "DeviceIndex: search_iters below the longest posting "
-                "list's bit length — the fold would miss matches"
+                "DeviceIndex: search_levels must cover the longest posting "
+                "list with one fence per level above 0 — the fold would "
+                "miss matches"
             )
+        _check_fences(padded, jax.device_get(self.fences), "DeviceIndex")
         for i, lev in enumerate(self.levels):
             cl_ptr = jax.device_get(lev.cl_ptr)
             cl_ids = jax.device_get(lev.cl_ids)
@@ -218,9 +283,10 @@ def device_index(cidx) -> DeviceIndex:
         return cached
     index = hidx.index
     lens = np.diff(index.post_ptr)
-    max_len = int(lens.max()) if len(lens) else 0
+    levels = _search_levels(int(lens.max()) if len(lens) else 0)
+    post_docs = _pad_tiles(np.asarray(index.post_docs, np.int32))
     di = DeviceIndex(
-        post_docs=jax.device_put(np.asarray(index.post_docs, np.int32)),
+        post_docs=jax.device_put(post_docs),
         post_ptr=jax.device_put(np.asarray(index.post_ptr, np.int64)),
         levels=tuple(
             DeviceLevel(
@@ -234,7 +300,8 @@ def device_index(cidx) -> DeviceIndex:
         ),
         n_docs=index.n_docs,
         n_postings=len(index.post_docs),
-        search_iters=max(max_len.bit_length(), 1),
+        fences=tuple(jax.device_put(f) for f in _fences(post_docs, levels)),
+        search_levels=levels,
         host=hidx,
     )
     maybe_validate(di)  # REPRO_DEBUG: structural check before caching
@@ -273,7 +340,7 @@ class LoweredPlan:
     group_width: int  # quantized per-stage width of stage_seg
     cell_prefix: Tuple[int, ...]  # true active cells per stage (host info)
     group_prefix: Tuple[int, ...]  # true active groups per stage
-    stage_iters: Tuple[int, ...]  # static per-stage binary-search depth
+    stage_levels: Tuple[int, ...]  # static per-stage 128-ary search depth
     order: np.ndarray  # (G,) the arity-descending group permutation
     cell_counts: np.ndarray  # (G,) cells per permuted group (= rank-0 len)
     n_queries: int
@@ -286,7 +353,7 @@ class LoweredPlan:
 
     @property
     def n_stages(self) -> int:
-        return len(self.stage_iters)
+        return len(self.stage_levels)
 
     def stage_len_sum(self, s: int) -> int:
         w = self.group_width
@@ -323,7 +390,7 @@ def lower_plan(plan) -> LoweredPlan:
     group_width = _quantize(len(order))
     cell_prefix: List[int] = []
     group_prefix: List[int] = []
-    stage_iters: List[int] = []
+    stage_levels: List[int] = []
     seg_parts: List[np.ndarray] = []
     for s in range(1, int(plan.max_arity)):
         # Groups still active at stage s are those with arity > s — a
@@ -341,11 +408,9 @@ def lower_plan(plan) -> LoweredPlan:
         group_prefix.append(n_g)
         cell_prefix.append(int(cell_cum[n_g]))
         # The probed segments are cluster-local slices, usually far
-        # shorter than the longest posting list: size the binary search
-        # to THIS stage's longest segment (rounded up to even depth so
-        # close batches share a compiled executable).
-        it = max(int(lens.max()).bit_length(), 1)
-        stage_iters.append(it + (it & 1))
+        # shorter than the longest posting list: size the search to THIS
+        # stage's longest segment.
+        stage_levels.append(_search_levels(int(lens.max())))
     stage_seg = (
         np.concatenate(seg_parts, axis=1)
         if seg_parts
@@ -357,7 +422,7 @@ def lower_plan(plan) -> LoweredPlan:
         group_width=group_width,
         cell_prefix=tuple(cell_prefix),
         group_prefix=tuple(group_prefix),
-        stage_iters=tuple(stage_iters),
+        stage_levels=tuple(stage_levels),
         order=order,
         cell_counts=cell_counts,
         n_queries=n_queries,
@@ -371,32 +436,91 @@ def lower_plan(plan) -> LoweredPlan:
 # ----------------------------------------------------------------------
 
 
-def _search_segments(post_docs, cur, lo, hi, iters: int):
-    """Leftmost position of each ``cur`` element inside its own posting
-    segment ``post_docs[lo : hi]`` — a vectorized binary search with
-    per-element bounds, probing the resident array in place (no gather of
-    the long side, no padding)."""
-    n = post_docs.shape[0]
-    end = hi
+def _masked_rows(table, row, lo, hi, j: int):
+    """Rows ``row`` (cells × k row indices) of search level ``j`` —
+    ``table`` viewed as (rows, 128): fence j, or the postings at j = 0 —
+    as one (cells, k·128) slab, with the mask of the entries whose
+    position (entry × ``128**j``) lies inside ``[lo, hi)``, and each
+    cell's first such entry, ``ceil(lo / 128**j)``."""
+    rows_of = table.reshape(-1, _LANES)
+    width = row.shape[1] * _LANES
+    vals = rows_of[jnp.clip(row, 0, rows_of.shape[0] - 1)].reshape(-1, width)
+    shift = 7 * j  # log2(128**j)
+    first = (lo + (1 << shift) - 1) >> shift
+    end = (hi + (1 << shift) - 1) >> shift
+    entry = row[:, :1] * _LANES + jnp.arange(width, dtype=jnp.int32)
+    inside = (entry >= first[:, None]) & (entry < end[:, None])
+    return vals, inside, first
 
-    def body(_, state):
-        lo, hi = state
-        mid = (lo + hi) >> 1
-        v = post_docs[jnp.minimum(mid, n - 1)]
-        below = v < cur
-        return jnp.where(below, mid + 1, lo), jnp.where(below, hi, mid)
 
-    lo, _ = jax.lax.fori_loop(0, iters, body, (lo, hi))
-    found = (lo < end) & (post_docs[jnp.minimum(lo, n - 1)] == cur)
-    return found
+def _search_block(post_docs, fences, cur, lo, hi):
+    """``cur[i]`` in ``post_docs[lo[i] : hi[i]]``, for one block of cells.
+
+    A 128-ary descent from the top fence to the postings' own rows.  At
+    fence level j (stride ``S = 128**j``) a cell's candidates are the
+    entries at aligned positions inside its segment, ``ceil(lo / S) <= e
+    < ceil(hi / S)``; the segment is sorted (a slice of one term's list),
+    so the entries at or below ``cur`` are a prefix, and their count
+    ``c`` names the one stride-S block that holds the last posting at or
+    below ``cur``: entry ``max(ceil(lo / S), first of the row) + c - 1``.
+    The top level's segment spans at most 128 entries, which two aligned
+    rows hold; every level below searches inside one block, which one
+    row holds.  Level 0 tests equality on the postings themselves.
+    Every comparison is masked to ``[lo, hi)``, so rows that straddle
+    term boundaries (or reach the PAD tail) never match, and a cell whose
+    value is absent ends on a row where nothing equals it."""
+    levels = (post_docs,) + tuple(fences)
+    top = len(fences)
+    first = (lo + (1 << 7 * top) - 1) >> 7 * top
+    row = (first >> 7)[:, None] + jnp.arange(2, dtype=jnp.int32)
+    for j in range(top, 0, -1):
+        vals, inside, first = _masked_rows(levels[j], row, lo, hi, j)
+        below = (inside & (vals <= cur[:, None])).sum(axis=1, dtype=jnp.int32)
+        row = (jnp.maximum(first, row[:, 0] * _LANES) + below - 1)[:, None]
+    vals, inside, _ = _masked_rows(post_docs, row, lo, hi, 0)
+    return (inside & (vals == cur[:, None])).any(axis=1)
+
+
+def _search_segments(post_docs, fences, cur, lo, hi, n_live):
+    """Whether each ``cur`` element lies inside its own posting segment
+    ``post_docs[lo : hi]`` — searched in place in the resident array (no
+    gather of the long side, no padding) by :func:`_search_block`, over
+    ``len(fences) + 1`` levels.
+
+    Cells go through the search in blocks of ``_SEARCH_BLOCK``, so one
+    step's gathered rows stay a bounded slab; only the blocks that hold
+    the first ``n_live`` cells run (a traced count: the cells past it are
+    reported not found).  The last block is moved back to end at the
+    vector's end, so it may redo cells of the block before it, with the
+    same result."""
+    n = cur.shape[0]
+    blk = min(_SEARCH_BLOCK, n)
+    n_blocks = (n_live + blk - 1) // blk
+
+    def body(b, found):
+        at = jnp.minimum(b * blk, n - blk)
+        sl = lambda a: jax.lax.dynamic_slice_in_dim(a, at, blk)
+        hit = _search_block(post_docs, fences, sl(cur), sl(lo), sl(hi))
+        return jax.lax.dynamic_update_slice_in_dim(found, hit, at, 0)
+
+    return jax.lax.fori_loop(0, n_blocks, body, jnp.zeros(n, bool))
+
+
+def _search_reads(n_cells: int, n_live: int, levels: int) -> int:
+    """Row reads one stage's search issues: the cells its blocks carry
+    (:func:`_search_segments`) times the reads a cell makes — two rows at
+    the top level, one at each level below."""
+    blk = min(_SEARCH_BLOCK, n_cells)
+    return -(-n_live // blk) * blk * (levels + 1)
 
 
 def _fold_core(
     post_docs,
+    fences,
     cells,
     stage_seg,
     group_width: int,
-    stage_iters: Tuple[int, ...],
+    stage_levels: Tuple[int, ...],
     n_queries_pad: int,
     return_members: bool,
 ):
@@ -413,13 +537,14 @@ def _fold_core(
     Stage s filters only the cells whose group is still active
     (``arity > s``); finished groups and quantization-pad cells pass
     through untouched, so every shape here is a quantized static — the
-    jit cache key is (shapes, group_width, stage_iters, n_queries_pad),
-    shared by all batches of similar size.
+    jit cache key is (shapes, group_width, stage_levels, n_queries_pad),
+    shared by all batches of similar size.  Stage s searches with the
+    first ``stage_levels[s - 1] - 1`` fences, up to the last active cell.
 
     Named scopes give the device ops stable names in the profiler's
     trace, whatever HLO names the compiler picks: ``seclud.fold/gather``
     (the cells' first values), ``seclud.fold/stage{s}`` (stage s's
-    binary search) and ``seclud.fold/count`` (the ``segment_sum``).
+    segment search) and ``seclud.fold/count`` (the ``segment_sum``).
     """
     n = post_docs.shape[0]
     with jax.named_scope("seclud.fold"):
@@ -429,15 +554,19 @@ def _fold_core(
         with jax.named_scope("gather"):
             cur = post_docs[jnp.clip(cell_post, 0, n - 1)]
             cur = jnp.where(cell_post != PAD, cur, PAD)
+        position = jnp.arange(1, cell_arity.shape[0] + 1, dtype=jnp.int32)
         entering = []
-        for s, iters in enumerate(stage_iters, start=1):
+        for s, levels in enumerate(stage_levels, start=1):
             with jax.named_scope(f"stage{s}"):
                 seg = stage_seg[:, (s - 1) * group_width : s * group_width]
                 lo = seg[0][cell_group]
                 hi = lo + seg[1][cell_group]
                 act = cell_arity > s
                 entering.append(((cur != PAD) & act).sum())
-                found = _search_segments(post_docs, cur, lo, hi, iters)
+                n_live = jnp.max(jnp.where(act, position, 0))
+                found = _search_segments(
+                    post_docs, fences[: levels - 1], cur, lo, hi, n_live
+                )
                 cur = jnp.where(act & ~found, PAD, cur)
         with jax.named_scope("count"):
             counts = jax.ops.segment_sum(
@@ -452,7 +581,7 @@ _fused_fold = functools.partial(
     jax.jit,
     static_argnames=(
         "group_width",
-        "stage_iters",
+        "stage_levels",
         "n_queries_pad",
         "return_members",
     ),
@@ -464,7 +593,7 @@ _fused_fold = functools.partial(
 # ----------------------------------------------------------------------
 #
 # The fused fold's jit-cache key is the quantized shape tuple
-# (n_cells, group_width, stage_iters, n_queries_pad) — everything else
+# (n_cells, group_width, stage_levels, n_queries_pad) — everything else
 # is traced data.  A serving loop can therefore enumerate the keys its
 # batch plan will produce, compile each once on *dead* cell content
 # (all-PAD cells, zero segments — the fold is mask-safe by design), and
@@ -481,12 +610,12 @@ def fold_cache_size() -> int:
 
 def plan_shape_key(lowered: LoweredPlan) -> Tuple[int, int, Tuple[int, ...], int]:
     """The jit-cache key of a lowered plan: the quantized shape tuple
-    ``(n_cells, group_width, stage_iters, n_queries_pad)``.  Two plans
+    ``(n_cells, group_width, stage_levels, n_queries_pad)``.  Two plans
     with equal keys share one compiled executable."""
     return (
         lowered.n_cells,
         lowered.group_width,
-        lowered.stage_iters,
+        lowered.stage_levels,
         lowered.n_queries_pad,
     )
 
@@ -504,19 +633,20 @@ def warm_fold(
     execution cost.  The fold masks dead cells everywhere, so warming
     content never touches real postings.
     """
-    n_cells, group_width, stage_iters, n_queries_pad = key
+    n_cells, group_width, stage_levels, n_queries_pad = key
     cells = np.empty((4, n_cells), np.int32)
     cells[0] = PAD
     cells[1] = 0
     cells[2] = n_queries_pad
     cells[3] = 0
-    stage_seg = np.zeros((2, len(stage_iters) * group_width), np.int32)
+    stage_seg = np.zeros((2, len(stage_levels) * group_width), np.int32)
     out = _fused_fold(
         dindex.post_docs,
+        dindex.fences,
         jax.device_put(cells),
         jax.device_put(stage_seg),
         group_width=group_width,
-        stage_iters=tuple(stage_iters),
+        stage_levels=tuple(stage_levels),
         n_queries_pad=n_queries_pad,
         return_members=return_members,
     )
@@ -585,7 +715,8 @@ def prewarm(
 def _stage_info(lowered: LoweredPlan, entering: np.ndarray) -> List[Dict[str, float]]:
     """Per-stage attribution: how many cells the stage carried (padded),
     how many were live survivors (true), how many posting cells it probed
-    in place, and the resulting padding overhead."""
+    in place, the resulting padding overhead, and the 128-lane row reads
+    its search issued (``reads``)."""
     stages = []
     for s in range(len(lowered.cell_prefix)):
         carried = float(lowered.cell_prefix[s])
@@ -599,6 +730,13 @@ def _stage_info(lowered: LoweredPlan, entering: np.ndarray) -> List[Dict[str, fl
                 "long_cells": long_cells,
                 "padding_overhead": (carried + long_cells)
                 / max(live + long_cells, 1.0),
+                "reads": float(
+                    _search_reads(
+                        lowered.n_cells,
+                        lowered.cell_prefix[s],
+                        lowered.stage_levels[s],
+                    )
+                ),
             }
         )
     return stages
@@ -613,6 +751,7 @@ _EMPTY_INFO = {
     "cells": 0.0,
     "cells_true": 0.0,
     "upload_bytes": 0.0,
+    "search_reads": 0.0,
     "t_lower_s": 0.0,
     "t_fold_s": 0.0,
     "jit_compiles": 0.0,
@@ -642,7 +781,9 @@ def device_counts(
     all stages — the masked-execution analogue of pad waste), and
     ``stages`` (per-stage attribution dicts).  The cells the batch
     uploads: ``cells`` (padded cells the fold carries), ``cells_true``
-    and ``upload_bytes`` (the per-batch ``device_put`` bytes).  Per-call
+    and ``upload_bytes`` (the per-batch ``device_put`` bytes).
+    ``search_reads`` is the 128-lane row reads the stages' segment
+    searches issued (each stage's share is its ``reads``).  Per-call
     timing hooks for the serving loop ride along: ``t_plan_s`` /
     ``t_lower_s`` / ``t_fold_s`` split the call into host planning,
     lowering, and the fused dispatch (upload, dispatch and readback: the
@@ -685,10 +826,11 @@ def device_counts(
     with span("seclud.dispatch", info, "t_fold_s"):
         tally_d, members_d = _fused_fold(
             dindex.post_docs,
+            dindex.fences,
             cells_d,
             stage_seg_d,
             group_width=lowered.group_width,
-            stage_iters=lowered.stage_iters,
+            stage_levels=lowered.stage_levels,
             n_queries_pad=lowered.n_queries_pad,
             return_members=return_docs,
         )
@@ -712,6 +854,7 @@ def device_counts(
         cells=float(lowered.n_cells),
         cells_true=true_cells,
         upload_bytes=float(lowered.cells.nbytes + lowered.stage_seg.nbytes),
+        search_reads=sum(s["reads"] for s in stages),
         jit_compiles=float(fold_cache_size() - cache_before),
     )
     if not return_docs:
@@ -776,7 +919,10 @@ class ShardedDeviceIndex:
     its own row.  ``local_pos`` maps a global posting position to its
     position within its shard's row: a plan segment (contiguous globally,
     wholly inside one leaf cluster and therefore one shard) stays
-    contiguous locally, so lowering only remaps segment starts.
+    contiguous locally, so lowering only remaps segment starts.  W is a
+    whole number of (8, 128) tiles; ``fences`` stack each row's 128-ary
+    fences (:class:`DeviceIndex`) the same way, one (S, F_j) matrix per
+    fence level, ``search_levels - 1`` of them.
     """
 
     mesh: object  # jax.sharding.Mesh
@@ -787,13 +933,14 @@ class ShardedDeviceIndex:
     post_width: int  # W — quantized max shard posting count
     local_pos: np.ndarray  # (n_postings,) int64 — global -> within-shard
     shard_counts: np.ndarray  # (S,) int64 — true postings per shard
-    search_iters: int
+    fences: Tuple[object, ...]  # jax.Arrays (S, F_j), sharded P(data, None)
+    search_levels: int  # 128-ary levels covering the longest posting list
     host: HierIndex
 
     @property
     def nbytes(self) -> int:
         """Total resident bytes across the mesh (PAD tail included)."""
-        return int(self.post_docs.nbytes)
+        return int(self.post_docs.nbytes) + sum(int(f.nbytes) for f in self.fences)
 
     def validate(self) -> None:
         """Shard partition exactness (debug head: ``REPRO_DEBUG``).
@@ -803,7 +950,9 @@ class ShardedDeviceIndex:
         global posting sits at ``(shard_of(doc), local_pos)`` in its
         owner's row, rows carry nothing else but PAD tail, and the
         doc-range routing that ``lower_plan_sharded`` uses reproduces
-        the row assignment.
+        the row assignment.  Every row's fences equal the row at their
+        aligned positions, and ``search_levels`` covers the longest
+        posting list.
         """
         S = self.n_shards
         if len(self.top_bounds) != S + 1 or len(self.doc_bounds) != S + 1:
@@ -851,6 +1000,19 @@ class ShardedDeviceIndex:
             raise ValueError(
                 "ShardedDeviceIndex: non-PAD value outside the live partition"
             )
+        lens = np.diff(self.host.index.post_ptr)
+        if self.search_levels < _search_levels(
+            int(lens.max()) if len(lens) else 0
+        ) or len(self.fences) != self.search_levels - 1:
+            raise ValueError(
+                "ShardedDeviceIndex: search_levels must cover the longest "
+                "posting list with one fence per level above 0"
+            )
+        fences = jax.device_get(self.fences)
+        for s in range(S):
+            _check_fences(
+                stacked[s], [f[s] for f in fences], f"ShardedDeviceIndex row {s}"
+            )
 
 
 def sharded_device_index(
@@ -891,21 +1053,26 @@ def sharded_device_index(
     local_pos = np.empty(n_post, np.int64)
     local_pos[order] = local
     width = _quantize(int(shard_counts.max()) if n_post else 1)
+    width = -(-width // _TILE) * _TILE
     stacked = np.full((S, width), PAD, np.int32)
     stacked[shard_of, local_pos] = docs.astype(np.int32)
-    max_len = int(shard_counts.max()) if n_post else 0
+    lens = np.diff(hidx.index.post_ptr)
+    levels = _search_levels(int(lens.max()) if len(lens) else 0)
+    rows = NamedSharding(mesh, sh.postings_spec(mesh))
     sidx = ShardedDeviceIndex(
         mesh=mesh,
         n_shards=S,
         top_bounds=top_bounds,
         doc_bounds=doc_bounds,
-        post_docs=jax.device_put(
-            stacked, NamedSharding(mesh, sh.postings_spec(mesh))
-        ),
+        post_docs=jax.device_put(stacked, rows),
         post_width=width,
         local_pos=local_pos,
         shard_counts=shard_counts,
-        search_iters=max(max_len.bit_length(), 1),
+        fences=tuple(
+            jax.device_put(np.stack(f), rows)
+            for f in zip(*(_fences(row, levels) for row in stacked))
+        ),
+        search_levels=levels,
         host=hidx,
     )
     maybe_validate(sidx)  # REPRO_DEBUG: partition exactness before caching
@@ -961,7 +1128,8 @@ class ShardedLoweredPlan:
     cells: np.ndarray  # (S, 4, C) int32 — per-shard cell layout
     stage_seg: np.ndarray  # (S, 2, n_stages * group_width) int32
     group_width: int  # unified quantized per-stage width
-    stage_iters: Tuple[int, ...]  # per-stage max binary-search depth
+    stage_levels: Tuple[int, ...]  # per-stage max 128-ary search depth
+    stage_reads: Tuple[int, ...]  # per-stage row reads, all shards
     n_queries: int
     n_queries_pad: int
     n_cells_true: np.ndarray  # (S,) true cells per shard (load balance)
@@ -977,7 +1145,7 @@ class ShardedLoweredPlan:
 
     @property
     def n_stages(self) -> int:
-        return len(self.stage_iters)
+        return len(self.stage_levels)
 
 
 def lower_plan_sharded(plan, sidx: ShardedDeviceIndex) -> ShardedLoweredPlan:
@@ -1001,10 +1169,18 @@ def lower_plan_sharded(plan, sidx: ShardedDeviceIndex) -> ShardedLoweredPlan:
     width = max(low.group_width for _, low in lowereds.values())
     n_cells = max(low.n_cells for _, low in lowereds.values())
     n_stages = max(low.n_stages for _, low in lowereds.values())
-    iters = [0] * n_stages
+    levels = [1] * n_stages
     for _, low in lowereds.values():
-        for t, it in enumerate(low.stage_iters):
-            iters[t] = max(iters[t], it)
+        for t, lv in enumerate(low.stage_levels):
+            levels[t] = max(levels[t], lv)
+    reads = [
+        sum(
+            _search_reads(n_cells, low.cell_prefix[t], levels[t])
+            for _, low in lowereds.values()
+            if t < low.n_stages
+        )
+        for t in range(n_stages)
+    ]
     n_queries = plan.n_queries
 
     cells = np.empty((S, 4, n_cells), np.int32)
@@ -1034,7 +1210,8 @@ def lower_plan_sharded(plan, sidx: ShardedDeviceIndex) -> ShardedLoweredPlan:
         cells=cells,
         stage_seg=stage_seg,
         group_width=width,
-        stage_iters=tuple(iters),
+        stage_levels=tuple(levels),
+        stage_reads=tuple(reads),
         n_queries=n_queries,
         n_queries_pad=_quantize(n_queries),
         n_cells_true=n_true,
@@ -1050,7 +1227,7 @@ def lower_plan_sharded(plan, sidx: ShardedDeviceIndex) -> ShardedLoweredPlan:
 def _build_sharded_fold(
     mesh,
     group_width: int,
-    stage_iters: Tuple[int, ...],
+    stage_levels: Tuple[int, ...],
     n_queries_pad: int,
     return_members: bool,
 ):
@@ -1066,13 +1243,14 @@ def _build_sharded_fold(
     dp_axes = sh.batch_axes(mesh)
     cells_spec, seg_spec = sh.plan_specs(mesh)
 
-    def body(post_docs, cells, stage_seg):
+    def body(post_docs, fences, cells, stage_seg):
         tally, cur = _fold_core(
             post_docs[0],
+            tuple(f[0] for f in fences),
             cells[0],
             stage_seg[0],
             group_width=group_width,
-            stage_iters=stage_iters,
+            stage_levels=stage_levels,
             n_queries_pad=n_queries_pad,
             return_members=return_members,
         )
@@ -1089,7 +1267,7 @@ def _build_sharded_fold(
     fn = jax.shard_map(
         body,
         mesh=mesh,
-        in_specs=(sh.postings_spec(mesh), cells_spec, seg_spec),
+        in_specs=(sh.postings_spec(mesh), sh.postings_spec(mesh), cells_spec, seg_spec),
         out_specs=out_specs,
         check_vma=False,
     )
@@ -1121,8 +1299,9 @@ def sharded_device_counts(
     cells — the deterministic load-balance speedup bound) and
     ``load_balance`` (= agg_throughput / n_shards, the scaling
     efficiency).  ``cells`` counts every shard's padded cells (``n_shards
-    × n_cells``); ``cells_true``, ``upload_bytes`` and the ``t_*_s``
-    spans are as in :func:`device_counts`.  ``fault_hook`` is the chaos
+    × n_cells``); ``cells_true``, ``upload_bytes``, ``search_reads`` (with
+    each stage's ``reads`` in ``stages``, summed over shards) and the
+    ``t_*_s`` spans are as in :func:`device_counts`.  ``fault_hook`` is the chaos
     harness's injection point (:mod:`repro.serve.faults`): called inside
     the dispatch path, where it may raise scheduled faults and perturb
     ``shard_times``."""
@@ -1159,6 +1338,7 @@ def sharded_device_counts(
             shard_times=[0.0] * sidx.n_shards,
             agg_throughput=1.0,
             load_balance=1.0 / max(sidx.n_shards, 1),
+            stages=[],
         )
         if return_docs:
             return counts, np.empty(0, np.int32), info
@@ -1169,7 +1349,7 @@ def sharded_device_counts(
         fold = _build_sharded_fold(
             sidx.mesh,
             lowered.group_width,
-            lowered.stage_iters,
+            lowered.stage_levels,
             lowered.n_queries_pad,
             bool(return_docs),
         )
@@ -1181,7 +1361,7 @@ def sharded_device_counts(
         cells_d = jax.device_put(lowered.cells, NamedSharding(sidx.mesh, cells_spec))
         stage_seg_d = jax.device_put(lowered.stage_seg, NamedSharding(sidx.mesh, seg_spec))
     with span("seclud.dispatch", info, "t_fold_s"):
-        out = fold(sidx.post_docs, cells_d, stage_seg_d)
+        out = fold(sidx.post_docs, sidx.fences, cells_d, stage_seg_d)
     with span("seclud.readback", info, "t_fold_s"):
         counts = jax.device_get(out[0])[: lowered.n_queries].astype(np.int64)
     compiles = float(jit_cache_size(fold) - cache_before)
@@ -1210,6 +1390,11 @@ def sharded_device_counts(
         cells=float(lowered.n_shards * lowered.n_cells),
         cells_true=total_true,
         upload_bytes=float(lowered.cells.nbytes + lowered.stage_seg.nbytes),
+        search_reads=float(sum(lowered.stage_reads)),
+        stages=[
+            {"stage": float(t + 1), "reads": float(r)}
+            for t, r in enumerate(lowered.stage_reads)
+        ],
         jit_compiles=compiles,
     )
     if not return_docs:

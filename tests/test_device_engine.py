@@ -3,7 +3,9 @@ and the fused fold — counts AND docs bit-identical to the per-query loop
 at every depth and arity (the loop ≡ batched ≡ device property chain).
 """
 
+import jax
 import numpy as np
+import pytest
 from _hypothesis_compat import given, settings, st  # hypothesis, or fallback
 
 from repro.core.batched_query import batched_query, plan_segment_pairs
@@ -12,6 +14,9 @@ from repro.core.device_engine import (
     device_counts,
     device_index,
     lower_plan,
+    shard_mesh,
+    sharded_device_counts,
+    sharded_device_index,
 )
 from repro.core.queries import ConjunctiveQueries
 from repro.core.reorder import cluster_ranges, reorder_permutation
@@ -131,7 +136,12 @@ def test_device_index_is_cached_and_shared(rng):
     np.testing.assert_array_equal(
         np.asarray(di.levels[0].cl_ids), cidx.cl_ids
     )
-    np.testing.assert_array_equal(np.asarray(di.post_docs), cidx.index.post_docs)
+    # the resident postings are the host's, PAD-padded to whole tiles
+    resident = np.asarray(di.post_docs)
+    n = len(cidx.index.post_docs)
+    assert len(resident) % 1024 == 0
+    np.testing.assert_array_equal(resident[:n], cidx.index.post_docs)
+    assert (resident[n:] == PAD).all()
 
 
 def test_fit_shares_upload_with_cluster_index(small_corpus):
@@ -263,3 +273,96 @@ def test_device_docs_drop_pad_holes(rng):
     assert docs_dev.dtype == np.int32
     assert int(PAD) not in set(docs_dev.tolist())
     np.testing.assert_array_equal(docs_dev, docs)
+
+
+def _search_exact_setup(L):
+    """An index whose query ``[1, 0]`` probes term 0's segment of exactly
+    ``L`` postings in cluster 1.  Term 0 also fills all of cluster 0, so
+    that segment starts at an unaligned position, and it ends where term
+    1's list begins, so its last row straddles a term boundary.  Term 1
+    sits in ``min(L, 40)`` docs of cluster 1, half of them without term
+    0; term 2 fills every doc that would otherwise be empty."""
+    rng = np.random.default_rng(L)
+    c0 = 293  # cluster 0: term 0 only
+    hit = np.arange(L)  # cluster-1 docs (offsets) holding term 0
+    m = min(L, 40)
+    extra = max(m // 2, 1)
+    n1 = L + extra
+    with_t1 = set(rng.choice(L, m - m // 2, replace=False).tolist()) if L else set()
+    with_t1 |= {L + e for e in range(m // 2)}
+    rows = [[0] for _ in range(c0)]
+    for d in range(n1):
+        terms = ([0] if d < L else []) + ([1] if d in with_t1 else [])
+        rows.append(terms + [2] if not terms or d % 5 == 0 else terms)
+    ptr = np.concatenate([[0], np.cumsum([len(r) for r in rows])])
+    corpus = Corpus(doc_ptr=ptr.astype(np.int64),
+                    doc_terms=np.concatenate(rows).astype(np.int32), n_terms=3)
+    assign = np.concatenate([np.zeros(c0, int), np.ones(n1, int)])
+    reordered = permute_docs(build_index(corpus), reorder_permutation(assign, 2))
+    return build_cluster_index(reordered, cluster_ranges(assign, 2))
+
+
+@pytest.mark.parametrize("L", [0, 1, 127, 128, 129, 16383, 16384, 16385, 140_000])
+def test_segment_search_exact(L, monkeypatch):
+    """The 128-ary segment search answers exactly what ``np.isin`` over
+    the segment does, for a probed segment of ``L`` postings: at an
+    unaligned and an aligned start, next to other terms' postings in the
+    same rows, for PAD cells and past the live count; in small blocks, so
+    the last block overlaps the one before.  Then the whole fold, single
+    device and sharded, against the host ``batched_query``."""
+    from repro.core import device_engine as de
+
+    rng = np.random.default_rng(L + 1)
+    monkeypatch.setattr(de, "_SEARCH_BLOCK", 64)
+    # Direct: three lists back to back; the segment is a slice of the middle one.
+    before = np.sort(rng.choice(10**9, 93, replace=False)) + 10**9
+    term = np.sort(rng.choice(10**9, L + 37 + 50 + 128, replace=False))
+    after = np.sort(rng.choice(10**6, 70, replace=False))
+    post = np.concatenate([before, term, after]).astype(np.int32)
+    padded = de._pad_tiles(post)
+    fences = de._fences(padded, de._search_levels(len(term)))
+    lo1 = len(before) + 37  # unaligned
+    lo2 = -(-lo1 // 128) * 128  # aligned
+    cur, lo = [], []
+    for start in (lo1, lo2):
+        seg = post[start : start + L]
+        probe = np.concatenate([
+            seg if L <= 300 else rng.choice(seg, 300, replace=False),
+            post[[start - 1, min(start + L, len(post) - 1)]],  # just outside
+            before[:5], after[:5], rng.integers(0, 2**31 - 2, 20), [PAD],
+        ])
+        cur.append(probe)
+        lo.append(np.full(len(probe), start))
+    cur = np.concatenate(cur).astype(np.int32)
+    lo = np.concatenate(lo).astype(np.int32)
+    hi = lo + L
+    n_live = len(cur) - 7  # the last cells are past the live count
+    search = jax.jit(lambda *a: de._search_segments(*a))
+    levels = de._search_levels(L)
+    found = np.asarray(search(padded, fences[: levels - 1], cur, lo, hi, n_live))
+    want = np.array([np.isin(c, post[a:b]) for c, a, b in zip(cur, lo, hi)])
+    want[n_live:] = False
+    np.testing.assert_array_equal(found, want)
+    assert want.any() == (L > 0)
+
+    # The fold: [1, 0] probes the segment; [1] is inactive at stage 1;
+    # [1, 0, 2] adds a stage; quantization adds PAD cells.
+    cidx = _search_exact_setup(L)
+    cq = ConjunctiveQueries.from_lists([[1, 0], [1], [1, 0, 2], [0, 2], [2, 1]])
+    plan = plan_segment_pairs(cidx.as_hier(), cq, track_work=False)
+    if L > 1:
+        low = lower_plan(plan)
+        assert low.stage_seg[1, : low.group_width].max() == L
+        assert low.stage_levels[0] == levels
+        assert low.n_cells > low.n_cells_true  # PAD cells ride along
+    ptr, docs, _w = batched_query(cidx, cq)
+    mesh = shard_mesh(2)
+    for run in (
+        lambda: device_counts(cidx, cq, return_docs=True),
+        lambda: sharded_device_counts(
+            cidx, cq, sidx=sharded_device_index(cidx, mesh=mesh), return_docs=True),
+    ):
+        counts, docs_dev, info = run()
+        np.testing.assert_array_equal(counts, np.diff(ptr))
+        np.testing.assert_array_equal(docs_dev, docs)
+        assert info["search_reads"] == sum(s["reads"] for s in info["stages"])

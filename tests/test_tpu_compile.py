@@ -32,6 +32,7 @@ from repro.kernels.intersect.kernel import (
 
 N_POSTINGS = 100_000_000  # ~1M wiki-like documents, the smoke's default
 LONG_WIDTH = 16_384  # a leaf-cluster segment of a frequent term at that size
+LEVELS = 3  # 128-ary levels covering a posting list of up to 2M documents
 
 
 @pytest.fixture(scope="module")
@@ -60,6 +61,16 @@ def _spec(shape, sharding):
     return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
 
 
+def _tiles(n):
+    """``n`` entries padded to whole (8, 128) int32 tiles, as resident."""
+    return -(-n // 1024) * 1024
+
+
+def _fence_lens(n):
+    """Lengths of the resident fences of ``n`` postings at ``LEVELS``."""
+    return [_tiles(-(-_tiles(n) // 128**j)) for j in range(1, LEVELS)]
+
+
 @pytest.mark.parametrize(
     "kernel",
     [intersect_count_kernel, intersect_members_kernel, intersect_members_count_kernel],
@@ -73,13 +84,14 @@ def test_intersect_kernel_lowers_for_v5e(one_chip, kernel):
 
 
 def test_fused_fold_compiles_at_smoke_size(one_chip):
-    group_width, stage_iters = 512, (20, 20, 20, 20)  # arity-5 queries, 1M docs
+    group_width, stage_levels = 512, (LEVELS,) * 4  # arity-5 queries, 1M docs
     compiled = _fused_fold.lower(
-        _spec((N_POSTINGS,), one_chip),
+        _spec((_tiles(N_POSTINGS),), one_chip),
+        tuple(_spec((n,), one_chip) for n in _fence_lens(N_POSTINGS)),
         _spec((4, 1 << 16), one_chip),
-        _spec((2, len(stage_iters) * group_width), one_chip),
+        _spec((2, len(stage_levels) * group_width), one_chip),
         group_width=group_width,
-        stage_iters=stage_iters,
+        stage_levels=stage_levels,
         n_queries_pad=64,
         return_members=True,
     ).compile()
@@ -90,14 +102,16 @@ def test_fused_fold_compiles_at_smoke_size(one_chip):
 
 def test_sharded_fold_compiles_on_2x2_with_one_all_reduce(topo):
     mesh = Mesh(np.asarray(topo.devices).reshape(4, 1), ("data", "model"))
-    group_width, stage_iters = 512, (16, 16)
-    fold = _build_sharded_fold(mesh, group_width, stage_iters, 64, False)
+    group_width, stage_levels = 512, (LEVELS, LEVELS)
+    fold = _build_sharded_fold(mesh, group_width, stage_levels, 64, False)
     rows = NamedSharding(mesh, P("data", None))
     plan = NamedSharding(mesh, P("data", None, None))
+    width = _tiles(N_POSTINGS // 4)
     compiled = fold.lower(
-        _spec((4, N_POSTINGS // 4), rows),
+        _spec((4, width), rows),
+        tuple(_spec((4, n), rows) for n in _fence_lens(width)),
         _spec((4, 4, 1 << 14), plan),
-        _spec((4, 2, len(stage_iters) * group_width), plan),
+        _spec((4, 2, len(stage_levels) * group_width), plan),
     ).compile()
     hlo = compiled.as_text()
     assert len(re.findall(r"\ball-reduce(?:-start)?\(", hlo)) == 1, hlo[:2000]

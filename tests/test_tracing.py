@@ -176,15 +176,16 @@ def test_fold_ops_carry_stable_scope_names(service, queries):
     dindex = device_index(service.query_index)
     cq = as_queries(queries[:40])
     low = lower_plan(plan_segment_pairs(dindex.host, cq, track_work=False))
-    assert len(low.stage_iters) >= 2
+    assert len(low.stage_levels) >= 2
     text = _fused_fold.lower(
-        dindex.post_docs, low.cells, low.stage_seg, group_width=low.group_width,
-        stage_iters=low.stage_iters, n_queries_pad=low.n_queries_pad,
+        dindex.post_docs, dindex.fences, low.cells, low.stage_seg,
+        group_width=low.group_width,
+        stage_levels=low.stage_levels, n_queries_pad=low.n_queries_pad,
         return_members=False,
     ).compile().as_text()
     for scope in ["seclud.fold/gather", "seclud.fold/count"] + [
-            f"seclud.fold/stage{s + 1}/" for s in range(len(low.stage_iters))]:
+            f"seclud.fold/stage{s + 1}/" for s in range(len(low.stage_levels))]:
         assert scope in text, scope
-    # The stage loops are the binary searches: each while op is scoped.
+    # The stage loops are the segment searches: each while op is scoped.
     whiles = [ln for ln in text.splitlines() if " while(" in ln]
     assert whiles and all("seclud.fold/stage" in ln for ln in whiles)

@@ -119,8 +119,50 @@ def test_device_index_rejects_corruption(cidx):
     bad = dataclasses.replace(di, n_docs=1)  # postings now out of range
     with pytest.raises(ValueError, match="doc ids"):
         bad.validate()
-    bad = dataclasses.replace(di, search_iters=0)
+    bad = dataclasses.replace(di, search_levels=0)
     with pytest.raises(ValueError):
+        bad.validate()
+
+
+@pytest.fixture(scope="module")
+def long_cidx():
+    """An index with a 300-posting list, so the resident postings carry
+    one fence."""
+    rng = np.random.default_rng(13)
+    n_docs, k = 300, 3
+    rows = [np.unique(np.concatenate([[0], rng.integers(1, 40, 6)])) for _ in range(n_docs)]
+    corpus = Corpus(
+        doc_ptr=np.concatenate([[0], np.cumsum([len(r) for r in rows])]).astype(np.int64),
+        doc_terms=np.concatenate(rows).astype(np.int32),
+        n_terms=40,
+    )
+    assign = rng.integers(0, k, n_docs)
+    reordered = permute_docs(build_index(corpus), reorder_permutation(assign, k))
+    return build_cluster_index(reordered, cluster_ranges(assign, k))
+
+
+@pytest.mark.parametrize("sharded", [False, True], ids=["single", "sharded"])
+@pytest.mark.parametrize("entry", [1, -1], ids=["aligned_posting", "pad_tail"])
+def test_corrupt_fence_fails_validate(long_cidx, sharded, entry):
+    """A fence entry that disagrees with the posting at its aligned
+    position (or a PAD tail entry overwritten) fails ``validate()``: the
+    segment search would descend into the wrong block."""
+    import jax
+
+    if sharded:
+        idx = sharded_device_index(long_cidx, mesh=shard_mesh(2))
+        fence = np.asarray(idx.fences[0]).copy()
+        fence[0, entry] ^= 1
+        shard = idx.fences[0].sharding
+    else:
+        idx = device_index(long_cidx)
+        fence = np.asarray(idx.fences[0]).copy()
+        fence[entry] ^= 1
+        shard = idx.fences[0].sharding
+    assert idx.search_levels == 2
+    idx.validate()  # the index as built passes
+    bad = dataclasses.replace(idx, fences=(jax.device_put(fence, shard),))
+    with pytest.raises(ValueError, match="fence"):
         bad.validate()
 
 
