@@ -1,8 +1,10 @@
 """The fold's share of its memory roofline (%): the bytes an exact count
 must move (``bench/fold_bytes.py``, from the reference's answers) over the
-chip's HBM bandwidth, divided by the fold's device time in the trace.
-The requests and the trace cover the same interval: every request sent
-from the window's start, each answered by a fold the trace holds."""
+HBM bandwidth of the chips the fold runs on (the chip's bandwidth times
+the cell's chips), divided by the fold's device time per chip in the
+trace.  The requests and the trace cover the same interval: every
+request sent from the window's start, each answered by a fold the trace
+holds."""
 
 from bench import fold_bytes as _fb
 
@@ -15,4 +17,4 @@ def read(rec):
     r = rec.requests
     done = r.qid[r.count >= 0]
     need = _fb.needed_bytes(rec.arities[done], rec.want[done])
-    return 100.0 * need / bw / t["fold_device_s"] if need else None
+    return 100.0 * need / (bw * rec.chips) / t["fold_device_s"] if need else None

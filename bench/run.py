@@ -125,6 +125,7 @@ class Record:
     want: Optional[np.ndarray]  # the reference's count per query of the stream
     trace: Optional[dict]  # trace_reduce.reduce(...) with --trace 1
     peaks: dict  # the device's row of peaks.json
+    chips: int = 1  # the chips the cell runs on
 
     def window_batches(self):
         return [b for b in self.batches if self.t0 <= b.t_start < self.t1]
@@ -160,14 +161,29 @@ def _control_counts(ref, cq, _counts) -> np.ndarray:
     return np.array([ref.count(cq.terms(i), CONTROL_BITS) for i in range(cq.n_queries)], np.int64)
 
 
-def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, rehearse: bool = False,
-             fault: Optional[Callable] = None, grace_s: float = 60.0,
-             control: bool = False) -> dict:
-    """One run of ``cell``.  Returns the result object (the last line).
+@dataclasses.dataclass
+class Deployment:
+    """What every run of a cell shares: the device, the configuration's
+    corpus and query pool, and the program's service over the fitted
+    index (sharded over the cell's chips where it asks for more than
+    one).  Built once from the configuration's own seed."""
 
-    ``fault(cq, counts)`` rewrites each batch's counts on their way back
-    (self-tests); ``control`` puts the reference at 16-bit document ids in
-    the program's place, which the comparison has to fail."""
+    cell: Cell
+    jax: object
+    device: dict
+    peaks: dict
+    devs: list
+    corpus: object
+    pool: object  # data.QueryLog
+    cost: np.ndarray  # per pool query: its shortest posting list
+    svc: object  # SearchService; None once freed
+    traces: list  # one counter: jaxpr traces so far in this process
+    want_pool: np.ndarray  # the reference's count per pool query, -1 until read
+
+
+def build(cell: Cell, rehearse: bool = False) -> Deployment:
+    """JAX, the corpus, the fit and the service of ``cell``: the part of
+    set-up that does not depend on the run's seed."""
     if not (ROOT / "src" / "repro").is_dir():
         raise NoDevice(f"no program: {ROOT / 'src' / 'repro'} is missing")
     sys.path.insert(0, str(ROOT / "src"))
@@ -185,10 +201,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, rehearse: bool 
     peaks = peaks_table.get(device["kind"], {})
     devs = jax.devices()[: cell.chips]
 
-    from bench import data, drive, reference, trace_reduce
-    from repro.core.device_engine import fold_cache_size, prewarm
+    from bench import data
     from repro.core.seclud import SecludPipeline
-    from repro.serve.loop import AsyncServingLoop, ServeConfig
     from repro.serve.search_service import SearchService
 
     traces = [0]
@@ -223,36 +237,87 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, rehearse: bool 
     svc = SearchService(res)
     log(f"setup: fit clusters={res.k} post_docs_bytes={int(svc.device_index.post_docs.nbytes)} "
         f"in {time.perf_counter() - t_stage:.3f} s")
+    if cell.chips > 1:
+        # The program's own sharded path: every later batch goes through
+        # serve_counts_device -> sharded_device_counts over the cell's chips.
+        t_stage = time.perf_counter()
+        sidx = svc.enable_sharded(n_shards=cell.chips)
+        log(f"setup: sharded n_shards={sidx.n_shards} "
+            f"shard_postings={[int(c) for c in sidx.shard_counts]} "
+            f"top_bounds={[int(b) for b in sidx.top_bounds]} in {time.perf_counter() - t_stage:.3f} s")
 
-    # The stream: one pool of queries from the configuration's seed, the
-    # same work for every run, served in passes whose order comes from the
-    # run's seed, each batch a spread of the pool's cost strata (cost: the
-    # shortest posting list of the query, a bound on the cells the fold
-    # visits).  A closed loop seals consecutive runs of the stream and
-    # cycles it, so its batches are the ones prewarmed.
-    batch = int(cfg["serve"]["max_batch"])
-    n_pool, n_stream = int(tr["pool_queries"]), int(tr["stream_queries"])
-    if n_stream % n_pool:
-        raise ValueError("stream_queries must be a multiple of pool_queries")
-    pool = queries(n_pool, _seed(data_seed, 2))
+    # The pool: one set of queries from the configuration's seed, the
+    # same work for every run; its cost (the shortest posting list of the
+    # query, a bound on the cells the fold visits) sets the strata the
+    # run's seed orders it by.
+    pool = queries(int(tr["pool_queries"]), _seed(data_seed, 2))
     df = corpus.term_doc_freq()
     cost = np.where(pool.queries >= 0, df[np.maximum(pool.queries, 0)], np.iinfo(np.int64).max)
-    order = data.serve_order(cost.min(axis=1), batch, n_stream // n_pool, _seed(seed, 3))
-    stream = pool.queries[order]
+    return Deployment(cell=cell, jax=jax, device=device, peaks=peaks, devs=devs, corpus=corpus,
+                      pool=pool, cost=cost.min(axis=1), svc=svc, traces=traces,
+                      want_pool=np.full(pool.n_queries, -1, np.int64))
+
+
+def _prewarm_served(svc, stream: np.ndarray, windows, chips: int) -> int:
+    """Serve each window of the stream once through the program's own
+    entry, for a path that has no compile-only prewarm (the sharded
+    fold): every shape the window will use compiles here.  Fails at once
+    where the program does not serve over the cell's chips.  Returns the
+    compiles it caused."""
+    compiles = 0
+    for i, j in windows:
+        _counts, info = svc.serve_counts_device(stream[i:j])
+        if int(info.get("n_shards", 1)) != chips:
+            raise RuntimeError(f"the cell asks for {chips} chips; the program served a batch "
+                               f"on {info.get('n_shards', 1)} shard(s)")
+        compiles += int(info.get("jit_compiles", 0))
+    return compiles
+
+
+def run_seed(dep: Deployment, seed: int, seconds: float, trace: bool,
+             fault: Optional[Callable] = None, grace_s: float = 60.0,
+             control: bool = False, free: bool = False) -> dict:
+    """One run of ``dep``'s cell from its seed on: order the pool into a
+    stream, prewarm, warm up, measure for ``seconds``, check every
+    answer.  Returns the result object (the last line).  ``free`` drops
+    the program's state before the reference runs (the last run of a
+    process)."""
+    from bench import data, drive, reference, trace_reduce
+    from repro.core.device_engine import fold_cache_size, prewarm
+    from repro.serve.loop import AsyncServingLoop, ServeConfig
+
+    jax, cell, svc, traces = dep.jax, dep.cell, dep.svc, dep.traces
+    cfg, tr = cell.config, cell.traffic
+
+    # The stream: the pool served in passes whose order comes from the
+    # run's seed, each batch a spread of the pool's cost strata.  A closed
+    # loop seals consecutive runs of the stream and cycles it, so its
+    # batches are the ones prewarmed.
+    batch = int(cfg["serve"]["max_batch"])
+    n_pool, n_stream = dep.pool.n_queries, int(tr["stream_queries"])
+    if n_stream % n_pool:
+        raise ValueError("stream_queries must be a multiple of pool_queries")
+    order = data.serve_order(dep.cost, batch, n_stream // n_pool, _seed(seed, 3))
+    stream = dep.pool.queries[order]
     terms = data.QueryLog(stream).term_lists()
     arities = data.QueryLog(stream).arities()
 
     t_stage = time.perf_counter()
     windows = [(i, i + batch) for i in range(0, n_stream, batch)]
-    pw = prewarm(svc.query_index, stream, batches=windows, dindex=svc.device_index)
-    log(f"setup: prewarm keys={pw['n_keys']} compiles={pw['n_compiles']} "
-        f"in {time.perf_counter() - t_stage:.3f} s")
+    if cell.chips > 1:
+        n = _prewarm_served(svc, stream, windows, cell.chips)
+        log(f"setup: prewarm served batches={len(windows)} compiles={n} "
+            f"in {time.perf_counter() - t_stage:.3f} s")
+    else:
+        pw = prewarm(svc.query_index, stream, batches=windows, dindex=svc.device_index)
+        log(f"setup: prewarm keys={pw['n_keys']} compiles={pw['n_compiles']} "
+            f"in {time.perf_counter() - t_stage:.3f} s")
 
     if control:
         # The reference at 16-bit document ids answers in the program's
         # place, on the same batches through the same loop.
         fault = functools.partial(_control_counts,
-                                  reference.TermLists(corpus, [t for q in terms for t in q]))
+                                  reference.TermLists(dep.corpus, [t for q in terms for t in q]))
 
     engine = drive.Engine(svc.serve_counts_device, jax.profiler.TraceAnnotation, fault)
     loop = AsyncServingLoop(
@@ -285,6 +350,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, rehearse: bool 
         state["fold_compiles"] = fold_cache_size() - state["fold_before"]
         state["traces"] = traces[0] - state["traces_before"]
         state["batches"] = engine.batches[n_batches:]
+        if cell.chips > 1:  # the sharded fold's compiles, which its jit caches count
+            state["fold_compiles"] += int(sum(b.info.get("jit_compiles", 0)
+                                              for b in state["batches"]))
         try:
             await asyncio.wait_for(loop.stop(), timeout=grace_s)
         except Exception as e:  # the loop died or hung: its answers never came
@@ -292,13 +360,23 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, rehearse: bool 
 
     asyncio.run(session())
     reqs = state["reqs"]
-    mem = peak_memory(devs)
+    mem = peak_memory(dep.devs)
 
     # Earlier lines: what the window did besides its metrics.
     sizes, freq = np.unique([b.size for b in state["batches"]], return_counts=True)
     log(f"window: requests={len(reqs.qid)} batches={len(state['batches'])} "
         f"compiles_in_window fold={state['fold_compiles']} traces={state['traces']} (expected 0)")
     log("window: batch sizes " + json.dumps({int(s): int(c) for s, c in zip(sizes, freq, strict=True)}))
+    if cell.chips > 1:
+        shards = sorted({int(b.info.get("n_shards", 1)) for b in state["batches"]})
+        cells = np.array([b.info["shard_cells"] for b in state["batches"]
+                          if len(b.info.get("shard_cells", ())) == cell.chips]).reshape(-1, cell.chips)
+        log(f"window: sharded n_shards={shards} batches_with_an_idle_shard="
+            f"{int((cells == 0).any(axis=1).sum())} shard_cells_mean="
+            f"{[round(float(c), 1) for c in cells.mean(axis=0)] if len(cells) else []}")
+        if set(shards) - {cell.chips}:
+            raise RuntimeError(f"the cell asks for {cell.chips} chips; the window's batches were "
+                               f"served on {shards} shard(s)")
     service = np.array([b.t_end - b.t_start for b in state["batches"]])
     if len(service):
         log(f"window: batch service s mean={service.mean():.6f} sd={service.std():.6f} "
@@ -309,16 +387,19 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, rehearse: bool 
     tr_red = trace_reduce.reduce(trace_reduce.load(str(trace_dir))) if trace else None
 
     # Free the program's state, then run the reference on every answer.
-    del svc, res, loop, engine
+    del loop, engine, svc
+    if free:
+        dep.svc = None
     t_stage = time.perf_counter()
     answered = ~np.isnan(reqs.reply)
     asked = np.unique(reqs.qid)
     want = np.full(n_stream, -1, np.int64)
     in_pool = np.unique(order[asked])
-    want_pool = np.full(n_pool, -1, np.int64)
-    want_pool[in_pool] = reference.reference_counts(
-        corpus, data.QueryLog(pool.queries[in_pool]).term_lists())
-    want[asked] = want_pool[order[asked]]
+    todo = in_pool[dep.want_pool[in_pool] < 0]
+    if len(todo):
+        dep.want_pool[todo] = reference.reference_counts(
+            dep.corpus, data.QueryLog(dep.pool.queries[todo]).term_lists())
+    want[asked] = dep.want_pool[order[asked]]
     wrong = int((reqs.count[answered] != want[reqs.qid[answered]]).sum())
     unanswered = int((~answered).sum())
     log(f"check: {int(answered.sum())} answers against the reference in "
@@ -326,7 +407,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, rehearse: bool 
 
     rec = Record(
         setup_s=state["setup_s"], t0=state["t0"], t1=state["t1"], requests=reqs,
-        batches=state["batches"], arities=arities, want=want, trace=tr_red, peaks=peaks)
+        batches=state["batches"], arities=arities, want=want, trace=tr_red, peaks=dep.peaks,
+        chips=cell.chips)
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
         v = load_metric(m["name"])(rec)
@@ -336,7 +418,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, rehearse: bool 
     checks = {"wrong": {"value": wrong, "limit": 0},
               "unanswered": {"value": unanswered, "limit": 0}}
     correct = all(c["value"] <= c["limit"] for c in checks.values())
-    dev = dict(device, memory_peak_bytes=mem)
+    dev = dict(dep.device, memory_peak_bytes=mem)
     out = {"correct": correct, "attempted": int(len(reqs.qid)), "failed": wrong + unanswered,
            "metrics": metrics, "device": dev}
     if trace:
@@ -344,6 +426,19 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, rehearse: bool 
         out["breakdown"] = {"device_ops": tr_red["device_ops"], "idle_gaps": tr_red["idle_gaps"]}
     out["checks"] = checks
     return out
+
+
+def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, rehearse: bool = False,
+             fault: Optional[Callable] = None, grace_s: float = 60.0,
+             control: bool = False) -> dict:
+    """One run of ``cell``.  Returns the result object (the last line).
+
+    ``fault(cq, counts)`` rewrites each batch's counts on their way back
+    (self-tests); ``control`` puts the reference at 16-bit document ids in
+    the program's place, which the comparison has to fail."""
+    dep = build(cell, rehearse)
+    return run_seed(dep, seed, seconds, trace, fault=fault, grace_s=grace_s,
+                    control=control, free=True)
 
 
 def main(argv=None) -> int:

@@ -30,13 +30,18 @@ import os
 from pathlib import Path
 from typing import Dict, List, Optional
 
-from bench.trace_reduce import FOLD_MODULE, WINDOW_SPAN, _short, _union, window
+from bench.trace_reduce import WINDOW_SPAN, _short, _union, is_fold, window
 
 # Where ``bench/run.py`` writes the trace of a ``--trace 1`` run.
 TRACE_DIR = Path(__file__).resolve().parent.parent / ".bench_out" / "trace"
 PREFIX = "seclud."
 SCOPE_STAT = "tf_op"  # the op metadata stat that holds its scope path
 STAGE_SCOPE = "seclud.fold/stage"
+# The sharded fold's cross-chip sum: the all-reduce that ``jax.lax.psum``
+# lowers to keeps the primitive's name as its HLO instruction name
+# (``%psum.7`` in a four-chip v5e trace of ``wiki4.closed``), which does
+# not depend on the scopes around it.
+PSUM_OP = "%psum"
 GROUPS = {
     "plan": ("seclud.plan",),
     "lower": ("seclud.lower",),
@@ -176,18 +181,22 @@ def reduce(trace: dict) -> Dict[str, object]:
     (``idle_ns``), the fold modules that start inside the window
     (``window_folds``), all fold modules (``fold_modules``) and the
     union of the device intervals of ops under a fold stage's scope
-    (``search_ns``, over the whole trace, as the fold's device time)."""
+    (``search_ns``, over the whole trace, as the fold's device time), and
+    likewise of the cross-chip sum's ops (``psum_ns``)."""
     win = window(trace)
     spans = [h for h in trace["host"] if h[0].startswith(PREFIX)]
     pieces = _innermost(spans)
     devs = [d for d in trace["devices"].values() if d["ops"] or d["modules"]]
     idle: Dict[str, float] = {}
-    window_folds = fold_modules = search = 0.0
+    window_folds = fold_modules = search = psum = 0.0
     for d in devs:
-        folds = [(s, du) for name, s, du in d["modules"] if FOLD_MODULE in name]
+        folds = [(s, du) for name, s, du in d["modules"] if is_fold(name)]
         fold_modules += len(folds)
         search += sum(b - a for a, b in _union(
             [(s, s + du) for _n, s, du, scope in d["ops"] if STAGE_SCOPE in scope]))
+        psum += sum(b - a for a, b in _union(
+            [(s, s + du) for name, s, du, _scope in d["ops"]
+             if name.split(".", 1)[0] == PSUM_OP]))
         if win is None:
             continue
         window_folds += sum(win[0] <= s < win[1] for s, _du in folds)
@@ -206,6 +215,7 @@ def reduce(trace: dict) -> Dict[str, object]:
         "window_folds": window_folds / n,
         "fold_modules": fold_modules / n,
         "search_ns": search / n,
+        "psum_ns": psum / n,
     }
 
 

@@ -86,3 +86,60 @@ def test_control_is_not_correct():
     out = run.run_cell(cell, 2**31 + 99, 1.0, trace=False, rehearse=True, control=True)
     assert out["correct"] is False
     assert out["checks"]["wrong"]["value"] > 0 and out["checks"]["unanswered"]["value"] == 0
+
+
+# -- the four-chip cell, on four virtual CPU devices ------------------------
+
+FOUR = {"XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
+
+
+def test_four_chip_rehearsal_on_a_cold_cache(tmp_path):
+    """``wiki4.closed`` serves every batch through the program's sharded
+    path over 4 devices, and nothing compiles inside the window even
+    with an empty compile cache."""
+    p = _cli(["--workload", "wiki4.closed", "--seed", "3000000019", "--seconds", "2",
+              "--trace", "0", "--rehearse"],
+             env_extra=dict(FOUR, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache")))
+    assert p.returncode == 0, p.stderr[-2000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    assert out["correct"] is True and out["failed"] == 0 and out["attempted"] > 0
+    assert out["device"]["count"] == 4
+    assert "setup: sharded n_shards=4 " in p.stdout
+    assert "window: sharded n_shards=[4] batches_with_an_idle_shard=0 " in p.stdout
+    assert "compiles_in_window fold=0 traces=0 " in p.stdout
+
+
+@pytest.fixture(scope="module")
+def sharded_cases():
+    """``sharded_cases.py``'s results, from one process with 4 devices."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", **FOUR)
+    p = subprocess.run([sys.executable, str(ROOT / "bench" / "tests" / "sharded_cases.py")],
+                       cwd=ROOT, env=env, capture_output=True, text=True, timeout=900)
+    assert p.returncode == 0, p.stderr[-2000:]
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("case,caught", [("sound", None), ("alter_one", "wrong"),
+                                         ("drop_half", "unanswered"), ("no_psum", "wrong"),
+                                         ("control", "wrong")])
+def test_four_chip_broken_path_is_not_correct(sharded_cases, case, caught):
+    """On ``wiki4.closed``: an answer altered where it is produced, half
+    of a batch left out, the exchange between chips (the psum) left out,
+    and the reference at 16-bit document ids in the program's place on a
+    corpus past 65,536 documents each read ``correct: false``."""
+    out = sharded_cases[case]
+    assert out["count"] == 4
+    if caught is None:
+        assert out["correct"] is True and out["checks"] == {"wrong": 0, "unanswered": 0}
+    else:
+        assert out["correct"] is False and out["checks"][caught] > 0
+
+
+def test_four_chip_prewarm_compiles_the_lowered_keys(sharded_cases):
+    """The sharded prewarm compiles each shape ``lower_plan_sharded``
+    gives the stream's windows once, and a second pass compiles nothing;
+    the program keeps one sharded fold per shape without the cell count."""
+    pw = sharded_cases["prewarm"]
+    assert pw["compiles"] == pw["keys"] > 0
+    assert pw["compiles_again"] == 0
+    assert pw["cached_folds"] == pw["folds"]

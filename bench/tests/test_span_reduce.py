@@ -116,3 +116,40 @@ def test_op_scopes_read_from_the_event_metadata(tmp_path):
     assert span_reduce._op_scopes(str(path)) == {"/device:TPU:0": {
         "%fusion.18 = s32[8] fusion()": "jit(_fold_core)/seclud.fold/stage1/while/body",
         "%fusion.5 = s32[8] fusion()": "jit(_fold_core)/seclud.fold/count"}}
+
+
+def test_psum_per_fold_averaged_over_the_chips(monkeypatch):
+    """The cross-chip sum: the union of each chip's ``%psum.<n>`` ops,
+    averaged over the chips, per fold."""
+    from bench.run import load_metric
+
+    host = [["bench.window", 0, 10_000_000]]
+    devices = {}
+    for i, wait in enumerate((100_000, 300_000, 500_000, 1_100_000)):
+        devices[f"/device:TPU:{i}"] = {
+            "ops": [["fusion.1", 0, 1_000_000, "jit(body)/shard_map/seclud.fold/stage1/while"],
+                    ["%psum.7", 1_000_000, wait, ""],
+                    ["fusion.2", 5_000_000, 1_000_000, "jit(body)/shard_map/seclud.fold/stage1/while"],
+                    ["%psum.7", 6_000_000, wait, ""],
+                    ["%psum_scatter.1", 8_000_000, 10, ""]],
+            "modules": [["jit_body(1)", 0, 1_000_000 + wait],
+                        ["jit_body(1)", 5_000_000, 1_000_000 + wait]]}
+    monkeypatch.setattr(span_reduce, "load", lambda _d: {"devices": devices, "host": host})
+    rec = types.SimpleNamespace(trace={}, window_batches=lambda: [])
+    r = span_reduce.for_record(rec)
+    assert r["fold_modules"] == 2 and r["search_ns"] == pytest.approx(2_000_000)
+    assert r["psum_ns"] == pytest.approx(2 * 500_000)
+    assert load_metric("psum_ms.closed")(rec) == pytest.approx(0.5)
+
+
+def test_shard_skew_over_the_window_batches():
+    from bench.run import load_metric
+
+    read = load_metric("shard_skew.closed")
+    infos = [{"shard_cells": [10.0, 10.0, 10.0, 10.0]}, {"shard_cells": [40.0, 0.0, 0.0, 0.0]},
+             {"shard_cells": [0.0, 0.0, 0.0, 0.0]}, {"cells": 5.0}]
+    rec = types.SimpleNamespace(window_batches=lambda: [types.SimpleNamespace(info=i)
+                                                        for i in infos])
+    assert read(rec) == pytest.approx((1.0 + 4.0) / 2)
+    one_chip = types.SimpleNamespace(window_batches=lambda: [types.SimpleNamespace(info={})])
+    assert read(one_chip) is None

@@ -15,9 +15,18 @@ import os
 from typing import Dict, List
 
 # Device operations of the fused fold run inside the program's jitted
-# ``_fold_core``; its module carries that name.
+# ``_fold_core``; its module carries that name.  The sharded fold is the
+# jit of a ``shard_map`` over the program's ``body`` closure around
+# ``_fold_core`` (``_build_sharded_fold``): its module is ``jit_body``.
 FOLD_MODULE = "_fold_core"
+SHARDED_FOLD_MODULE = "jit_body"
 WINDOW_SPAN = "bench.window"
+
+
+def is_fold(module: str) -> bool:
+    """Whether a device module event (``jit__fold_core(<id>)``) is a run
+    of the fold, on one chip or sharded."""
+    return FOLD_MODULE in module or module.split("(", 1)[0] == SHARDED_FOLD_MODULE
 
 
 def load(trace_dir: str) -> Dict[str, object]:
@@ -84,7 +93,7 @@ def reduce(trace: dict, top: int = 10) -> Dict[str, object]:
         busy.append(sum(b - a for a, b in merged))
         for name, _s, du in d["ops"]:
             by_op[name] = by_op.get(name, 0.0) + du
-        folds = [du for name, _s, du in d["modules"] if FOLD_MODULE in name]
+        folds = [du for name, _s, du in d["modules"] if is_fold(name)]
         fold_s.append(sum(folds))
         fold_n.append(len(folds))
         edges = [win[0]] if win is not None else []
