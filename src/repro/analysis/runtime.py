@@ -65,14 +65,17 @@ def maybe_validate(obj):
 def span(name: str, info: Optional[dict] = None, key: Optional[str] = None, **args):
     """A ``jax.profiler.TraceAnnotation`` named ``name`` (``args`` become
     its trace arguments) that also adds its ``time.perf_counter``
-    duration to ``info[key]``.  Spans sharing a key sum, so consecutive
-    spans time one interval together.  With no profiler running the
-    annotation costs one check.  (jax is imported here, not at module
-    level, so the lint head stays importable without it.)"""
+    duration to ``info[key]``, and yields its start on that clock.
+    Spans sharing a key sum; to time one interval across consecutive
+    spans, time it from the first one's start, since the instants
+    between the spans (where the interpreter may collect garbage) count
+    in the trace's interval and not in a sum.  With no profiler running
+    the annotation costs one check.  (jax is imported here, not at
+    module level, so the lint head stays importable without it.)"""
     from jax.profiler import TraceAnnotation
 
     with TraceAnnotation(name, **args):
         t0 = time.perf_counter()
-        yield
+        yield t0
         if key is not None:
             info[key] = info.get(key, 0.0) + (time.perf_counter() - t0)

@@ -51,6 +51,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -544,7 +545,9 @@ def _fold_core(
     Named scopes give the device ops stable names in the profiler's
     trace, whatever HLO names the compiler picks: ``seclud.fold/gather``
     (the cells' first values), ``seclud.fold/stage{s}`` (stage s's
-    segment search) and ``seclud.fold/count`` (the ``segment_sum``).
+    segment search), inside it ``seclud.fold/stage{s}/bounds`` (the
+    gather of every cell's segment bounds) and ``seclud.fold/count``
+    (the ``segment_sum``).
     """
     n = post_docs.shape[0]
     with jax.named_scope("seclud.fold"):
@@ -559,8 +562,9 @@ def _fold_core(
         for s, levels in enumerate(stage_levels, start=1):
             with jax.named_scope(f"stage{s}"):
                 seg = stage_seg[:, (s - 1) * group_width : s * group_width]
-                lo = seg[0][cell_group]
-                hi = lo + seg[1][cell_group]
+                with jax.named_scope("bounds"):
+                    lo = seg[0][cell_group]
+                    hi = lo + seg[1][cell_group]
                 act = cell_arity > s
                 entering.append(((cur != PAD) & act).sum())
                 n_live = jnp.max(jnp.where(act, position, 0))
@@ -742,6 +746,17 @@ def _stage_info(lowered: LoweredPlan, entering: np.ndarray) -> List[Dict[str, fl
     return stages
 
 
+def _late_info(stages: List[Dict[str, float]]) -> Dict[str, float]:
+    """The cells the stages after the first carry into their searches
+    (``late_cells``) and, of those, the ones still live on entering the
+    stage (``late_live``): the rest were ruled out by an earlier stage
+    and are searched all the same."""
+    return {
+        "late_cells": float(sum(s["cur_cells"] for s in stages[1:])),
+        "late_live": float(sum(s["cur_live"] for s in stages[1:])),
+    }
+
+
 # ``info`` of a batch with no (query, cluster) pair: nothing reaches the
 # device.
 _EMPTY_INFO = {
@@ -752,6 +767,8 @@ _EMPTY_INFO = {
     "cells_true": 0.0,
     "upload_bytes": 0.0,
     "search_reads": 0.0,
+    "late_cells": 0.0,
+    "late_live": 0.0,
     "t_lower_s": 0.0,
     "t_fold_s": 0.0,
     "jit_compiles": 0.0,
@@ -783,13 +800,19 @@ def device_counts(
     uploads: ``cells`` (padded cells the fold carries), ``cells_true``
     and ``upload_bytes`` (the per-batch ``device_put`` bytes).
     ``search_reads`` is the 128-lane row reads the stages' segment
-    searches issued (each stage's share is its ``reads``).  Per-call
+    searches issued (each stage's share is its ``reads``).
+    ``late_cells`` is the cells the stages after the first carry into
+    their searches, ``late_live`` those of them still live on entering
+    the stage (both sums over stages 2 on of the stages' ``cur_cells``
+    and ``cur_live``).  Per-call
     timing hooks for the serving loop ride along: ``t_plan_s`` /
     ``t_lower_s`` / ``t_fold_s`` split the call into host planning,
     lowering, and the fused dispatch (upload, dispatch and readback: the
     device round-trip); each is the duration of the profiler span of the
-    same interval (``seclud.plan``, ``seclud.lower``, then
-    ``seclud.upload`` + ``seclud.dispatch`` + ``seclud.readback``).
+    same interval (``seclud.plan``, ``seclud.lower``, then from the
+    start of ``seclud.upload`` to the end of ``seclud.readback``, timed
+    as one interval so that the instants between the three spans count
+    as they do in the trace).
     ``jit_compiles`` is the fold-cache growth this call caused (0 on
     every warm path).
     """
@@ -820,10 +843,10 @@ def device_counts(
     with span("seclud.lower", info, "t_lower_s"):
         lowered = lower_plan(plan)
     cache_before = fold_cache_size()
-    with span("seclud.upload", info, "t_fold_s"):
+    with span("seclud.upload") as t_fold:
         cells_d = jax.device_put(lowered.cells)
         stage_seg_d = jax.device_put(lowered.stage_seg)
-    with span("seclud.dispatch", info, "t_fold_s"):
+    with span("seclud.dispatch"):
         tally_d, members_d = _fused_fold(
             dindex.post_docs,
             dindex.fences,
@@ -834,9 +857,10 @@ def device_counts(
             n_queries_pad=lowered.n_queries_pad,
             return_members=return_docs,
         )
-    with span("seclud.readback", info, "t_fold_s"):
+    with span("seclud.readback"):
         tally = jax.device_get(tally_d)
         counts = tally[: lowered.n_queries].astype(np.int64)
+        info["t_fold_s"] = time.perf_counter() - t_fold
     entering = tally[lowered.n_queries_pad :]
 
     stages = _stage_info(lowered, entering)
@@ -855,6 +879,7 @@ def device_counts(
         cells_true=true_cells,
         upload_bytes=float(lowered.cells.nbytes + lowered.stage_seg.nbytes),
         search_reads=sum(s["reads"] for s in stages),
+        **_late_info(stages),
         jit_compiles=float(fold_cache_size() - cache_before),
     )
     if not return_docs:
@@ -1130,6 +1155,7 @@ class ShardedLoweredPlan:
     group_width: int  # unified quantized per-stage width
     stage_levels: Tuple[int, ...]  # per-stage max 128-ary search depth
     stage_reads: Tuple[int, ...]  # per-stage row reads, all shards
+    stage_cells: Tuple[int, ...]  # per-stage true active cells, all shards
     n_queries: int
     n_queries_pad: int
     n_cells_true: np.ndarray  # (S,) true cells per shard (load balance)
@@ -1181,6 +1207,10 @@ def lower_plan_sharded(plan, sidx: ShardedDeviceIndex) -> ShardedLoweredPlan:
         )
         for t in range(n_stages)
     ]
+    active = [
+        sum(low.cell_prefix[t] for _, low in lowereds.values() if t < low.n_stages)
+        for t in range(n_stages)
+    ]
     n_queries = plan.n_queries
 
     cells = np.empty((S, 4, n_cells), np.int32)
@@ -1212,6 +1242,7 @@ def lower_plan_sharded(plan, sidx: ShardedDeviceIndex) -> ShardedLoweredPlan:
         group_width=width,
         stage_levels=tuple(levels),
         stage_reads=tuple(reads),
+        stage_cells=tuple(active),
         n_queries=n_queries,
         n_queries_pad=_quantize(n_queries),
         n_cells_true=n_true,
@@ -1299,8 +1330,9 @@ def sharded_device_counts(
     cells — the deterministic load-balance speedup bound) and
     ``load_balance`` (= agg_throughput / n_shards, the scaling
     efficiency).  ``cells`` counts every shard's padded cells (``n_shards
-    × n_cells``); ``cells_true``, ``upload_bytes``, ``search_reads`` (with
-    each stage's ``reads`` in ``stages``, summed over shards) and the
+    × n_cells``); ``cells_true``, ``upload_bytes``, ``search_reads``,
+    ``late_cells``, ``late_live`` (with each stage's ``cur_cells``,
+    ``cur_live`` and ``reads`` in ``stages``, summed over shards) and the
     ``t_*_s`` spans are as in :func:`device_counts`.  ``fault_hook`` is the chaos
     harness's injection point (:mod:`repro.serve.faults`): called inside
     the dispatch path, where it may raise scheduled faults and perturb
@@ -1356,14 +1388,24 @@ def sharded_device_counts(
     cache_before = jit_cache_size(fold)
     # Explicit per-batch upload, pre-placed shard-per-row so the jit
     # never reshards (and never transfers implicitly).
-    with span("seclud.upload", info, "t_fold_s"):
+    with span("seclud.upload") as t_fold:
         cells_spec, seg_spec = sh.plan_specs(sidx.mesh)
         cells_d = jax.device_put(lowered.cells, NamedSharding(sidx.mesh, cells_spec))
         stage_seg_d = jax.device_put(lowered.stage_seg, NamedSharding(sidx.mesh, seg_spec))
-    with span("seclud.dispatch", info, "t_fold_s"):
+    with span("seclud.dispatch"):
         out = fold(sidx.post_docs, sidx.fences, cells_d, stage_seg_d)
-    with span("seclud.readback", info, "t_fold_s"):
-        counts = jax.device_get(out[0])[: lowered.n_queries].astype(np.int64)
+    with span("seclud.readback"):
+        tally = jax.device_get(out[0])
+        counts = tally[: lowered.n_queries].astype(np.int64)
+        info["t_fold_s"] = time.perf_counter() - t_fold
+    # The survivor totals entering each stage, summed over the shards by
+    # the fold's psum.
+    entering = tally[lowered.n_queries_pad :]
+    stages = [
+        {"stage": float(t + 1), "cur_cells": float(c), "cur_live": float(entering[t]),
+         "reads": float(r)}
+        for t, (c, r) in enumerate(zip(lowered.stage_cells, lowered.stage_reads, strict=True))
+    ]
     compiles = float(jit_cache_size(fold) - cache_before)
     total_true = float(lowered.n_cells_true.sum())
     max_true = float(lowered.n_cells_true.max())
@@ -1391,10 +1433,8 @@ def sharded_device_counts(
         cells_true=total_true,
         upload_bytes=float(lowered.cells.nbytes + lowered.stage_seg.nbytes),
         search_reads=float(sum(lowered.stage_reads)),
-        stages=[
-            {"stage": float(t + 1), "reads": float(r)}
-            for t, r in enumerate(lowered.stage_reads)
-        ],
+        stages=stages,
+        **_late_info(stages),
         jit_compiles=compiles,
     )
     if not return_docs:

@@ -4,7 +4,8 @@ The TPU compiler ships with jaxlib and compiles for a chip that is only
 described (``jax.experimental.topologies``).  These tests compile, at
 the widths the bring-up smoke (``chip_smoke.py``) serves, what the chip
 would run: the three Pallas intersect kernels, the fused fold over ~100M
-resident postings, and the sharded fold on a 2x2 mesh.  They catch what
+resident postings, the fold's stage scopes at the ``wiki`` benchmark's
+size, and the sharded fold on a 2x2 mesh.  They catch what
 interpret mode cannot — primitives Mosaic does not lower, unaligned
 slices, fast-memory overruns — and say nothing about results or times.
 
@@ -115,3 +116,29 @@ def test_sharded_fold_compiles_on_2x2_with_one_all_reduce(topo):
     ).compile()
     hlo = compiled.as_text()
     assert len(re.findall(r"\ball-reduce(?:-start)?\(", hlo)) == 1, hlo[:2000]
+
+
+def test_fused_fold_keeps_every_stage_bounds_gather_under_its_scope(one_chip):
+    """Each stage's gather of its cells' segment bounds stays an op of its
+    own under ``seclud.fold/stage<s>/bounds`` in the compiled fold, at the
+    shape of a batch of 3-5-term queries over the ``wiki`` benchmark's
+    41.55M postings: the trace's readers of the later stages' bounds
+    find it there."""
+    n_postings, stage_levels, group_width = 41_550_000, (2, 2, 2, 2), 4096
+    fences = [_tiles(-(-_tiles(n_postings) // 128**j)) for j in range(1, LEVELS)]
+    hlo = _fused_fold.lower(
+        _spec((_tiles(n_postings),), one_chip),
+        tuple(_spec((n,), one_chip) for n in fences),
+        _spec((4, 1 << 18), one_chip),
+        _spec((2, len(stage_levels) * group_width), one_chip),
+        group_width=group_width,
+        stage_levels=stage_levels,
+        n_queries_pad=64,
+        return_members=False,
+    ).compile().as_text()
+    entry = hlo[hlo.index("\nENTRY ") :]
+    for s in range(1, len(stage_levels) + 1):
+        ops = [ln for ln in entry.splitlines()
+               if f'op_name="jit(_fold_core)/seclud.fold/stage{s}/bounds/gather"' in ln
+               and " fusion(" in ln]
+        assert ops, f"stage {s}: no bounds gather of its own"

@@ -6,13 +6,16 @@ engine's ``seclud.plan`` / ``lower`` / ``upload`` / ``dispatch`` /
 ``readback`` and ``seclud.reply`` nested inside it in that order; the
 ``t_*_s`` keys of ``info`` are the durations of those same spans; both
 device paths count the padded cells they carry and the bytes they
-upload; and the fold's device ops carry stable ``seclud.fold/...`` scope
-names.
+upload, and the cells the stages after the first carry; and the fold's
+device ops carry stable ``seclud.fold/...`` scope names.
 """
 
 import asyncio
+import contextlib
+import gc
 import glob
 import os
+import time
 
 import jax
 import numpy as np
@@ -144,6 +147,11 @@ def test_info_counts_cells_and_upload_bytes(service, queries, sharded, tmp_path)
         assert info["cells_true"] == low.n_cells_true
     assert info["cells"] >= info["cells_true"] > 0
     assert info["upload_bytes"] == low.cells.nbytes + low.stage_seg.nbytes
+    # The cells stages 2 on carry, and of those the ones still live.
+    late = low.stage_cells[1:] if sharded else low.cell_prefix[1:]
+    assert info["late_cells"] == sum(late) > 0
+    assert 0 <= info["late_live"] <= info["late_cells"]
+    assert info["late_live"] == sum(s["cur_live"] for s in info["stages"][1:])
     assert counts.tolist() == service.serve_counts(cq)[0].tolist()
 
     by_name = {s[0]: s for s in spans}
@@ -160,6 +168,47 @@ def test_info_counts_cells_and_upload_bytes(service, queries, sharded, tmp_path)
     assert info["t_fold_s"] <= fold_interval
 
 
+@contextlib.contextmanager
+def _slow_collections():
+    """A garbage collection of about 1 ms due at every allocation."""
+    def slow(phase, _info):
+        if phase == "start":
+            time.sleep(0.001)
+
+    threshold = gc.get_threshold()
+    gc.callbacks.append(slow)
+    gc.set_threshold(1)
+    try:
+        yield
+    finally:
+        gc.set_threshold(*threshold)
+        gc.callbacks.remove(slow)
+
+
+@pytest.mark.parametrize("sharded", [False, True])
+def test_span_times_hold_through_garbage_collections(service, queries, sharded, tmp_path):
+    """A collection the interpreter runs inside a span, at its edges or
+    between the fold's three spans counts in the trace's interval and in
+    ``info`` alike: with one due at every allocation, each ``t_*_s``
+    still equals its span's duration."""
+    hidx = service.query_index
+    cq = as_queries(queries[40:80])
+    if sharded:
+        sidx = sharded_device_index(hidx, n_shards=2)
+        call = lambda: sharded_device_counts(hidx, cq, sidx=sidx)  # noqa: E731
+    else:
+        call = lambda: device_counts(hidx, cq)  # noqa: E731
+    call()  # compile outside the measured call
+    with _slow_collections():
+        (_counts, info), spans = _traced(tmp_path, call)
+    by_name = {s[0]: s for s in spans}
+    for key, name in (("t_plan_s", "seclud.plan"), ("t_lower_s", "seclud.lower")):
+        assert info[key] == pytest.approx((by_name[name][2] - by_name[name][1]) * 1e-9,
+                                          abs=2e-4), key
+    fold_interval = (by_name["seclud.readback"][2] - by_name["seclud.upload"][1]) * 1e-9
+    assert info["t_fold_s"] == pytest.approx(fold_interval, abs=2e-4)
+
+
 def test_empty_batch_info_counts_nothing(service):
     hidx = service.query_index
     df = np.diff(as_hier(hidx).index.post_ptr)
@@ -168,11 +217,12 @@ def test_empty_batch_info_counts_nothing(service):
         counts, info = fn(hidx, np.array([[absent, absent]]))
         assert counts.tolist() == [0]
         assert info["cells"] == info["cells_true"] == info["upload_bytes"] == 0.0
+        assert info["late_cells"] == info["late_live"] == 0.0
 
 
 def test_fold_ops_carry_stable_scope_names(service, queries):
-    """The compiled fold's op metadata names each stage's search, the
-    first gather and the count under ``seclud.fold``."""
+    """The compiled fold's op metadata names each stage's search and its
+    bounds gather, the first gather and the count under ``seclud.fold``."""
     dindex = device_index(service.query_index)
     cq = as_queries(queries[:40])
     low = lower_plan(plan_segment_pairs(dindex.host, cq, track_work=False))
@@ -184,7 +234,8 @@ def test_fold_ops_carry_stable_scope_names(service, queries):
         return_members=False,
     ).compile().as_text()
     for scope in ["seclud.fold/gather", "seclud.fold/count"] + [
-            f"seclud.fold/stage{s + 1}/" for s in range(len(low.stage_levels))]:
+            f"seclud.fold/stage{s + 1}/{sub}" for s in range(len(low.stage_levels))
+            for sub in ("", "bounds/")]:
         assert scope in text, scope
     # The stage loops are the segment searches: each while op is scoped.
     whiles = [ln for ln in text.splitlines() if " while(" in ln]
